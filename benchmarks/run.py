@@ -31,6 +31,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 def main() -> None:
     from benchmarks import common
     from benchmarks.paper_figures import ALL_BENCHES
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", choices=["host", "device"],

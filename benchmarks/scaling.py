@@ -155,13 +155,26 @@ def _hierarchy_worker(k_c: int, k_g: int, smoke: bool) -> None:
     print("RESULT:" + json.dumps(out))
 
 
+CPU_SIM = "cpu-simulated"  # every row of this bench comes from a CPU mesh
+
+
+def _cpu_simulated(rows: List[tuple]) -> List[tuple]:
+    """Label rows as measured on a simulated (forced host device) mesh."""
+    return [(name, value, f"{note} [{CPU_SIM}]")
+            for name, value, note in rows]
+
+
 def _spawn_worker(worker_args: List[str], n_dev: int, smoke: bool,
                   timeout: int = 1800) -> dict:
     """Spawn one benchmark worker subprocess with ``n_dev`` forced host
     devices and return its parsed ``RESULT:`` JSON line.  The XLA flag is
     appended (not overwritten) so user/CI XLA flags survive; ours comes
-    last, and the last occurrence of a repeated flag wins."""
+    last, and the last occurrence of a repeated flag wins.  The child runs
+    on the CPU platform explicitly: under ``benchmarks/run.py`` the parent
+    already holds the accelerator, and a simulated mesh is a CPU mesh by
+    construction."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n_dev}").strip()
@@ -213,9 +226,9 @@ def run_hierarchy(configs=((1, 4), (2, 2), (2, 4)), smoke: bool = False,
                or os.path.join(os.path.dirname(__file__), ".."))
     path = os.path.abspath(os.path.join(out_dir, "BENCH_hierarchy.json"))
     with open(path, "w") as f:
-        json.dump({"smoke": smoke, "configs": results}, f, indent=2,
-                  sort_keys=True)
-    return rows
+        json.dump({"smoke": smoke, "platform": CPU_SIM, "configs": results},
+                  f, indent=2, sort_keys=True)
+    return _cpu_simulated(rows)
 
 
 def run_scaling(device_counts=(1, 2, 4), smoke: bool = False) -> List[tuple]:
@@ -240,7 +253,7 @@ def run_scaling(device_counts=(1, 2, 4), smoke: bool = False) -> List[tuple]:
                          "intra-clique cross-device exchange"))
             rows.append((f"{pfx}/dev{d}/host_fill_bytes",
                          float(pd["host_fill_bytes"]), "true misses (PCIe)"))
-    return rows
+    return _cpu_simulated(rows)
 
 
 def main() -> None:

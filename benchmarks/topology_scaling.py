@@ -196,13 +196,25 @@ def _hierarchy_worker(smoke: bool) -> None:
     print("RESULT:" + json.dumps(out))
 
 
+CPU_SIM = "cpu-simulated"  # every row of this bench comes from a CPU mesh
+
+
+def _cpu_simulated(rows: List[tuple]) -> List[tuple]:
+    """Label rows as measured on a simulated (forced host device) mesh."""
+    return [(name, value, f"{note} [{CPU_SIM}]")
+            for name, value, note in rows]
+
+
 def _spawn_worker(worker_args: List[str], smoke: bool,
                   timeout: int = 1800) -> dict:
     """Spawn one worker subprocess with N_DEV forced host devices and
     return its parsed ``RESULT:`` JSON line.  The XLA flag is appended
     (not overwritten) so user/CI XLA flags survive; the last occurrence
-    of a repeated flag wins."""
+    of a repeated flag wins.  The child runs on the CPU platform
+    explicitly: under ``benchmarks/run.py`` the parent already holds the
+    accelerator, and a simulated mesh is a CPU mesh by construction."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={N_DEV}").strip()
@@ -299,9 +311,9 @@ def run_topology(smoke: bool = False, json_dir: str = None) -> List[tuple]:
                or os.path.join(os.path.dirname(__file__), ".."))
     path = os.path.abspath(os.path.join(out_dir, "BENCH_topology.json"))
     with open(path, "w") as f:
-        json.dump({"smoke": smoke, "arms": results}, f, indent=2,
-                  sort_keys=True)
-    return rows
+        json.dump({"smoke": smoke, "platform": CPU_SIM, "arms": results},
+                  f, indent=2, sort_keys=True)
+    return _cpu_simulated(rows)
 
 
 def main() -> None:

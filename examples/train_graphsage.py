@@ -16,6 +16,7 @@ from repro.core.planner import build_plan
 from repro.graph.csr import powerlaw_graph
 from repro.models.gnn import GNNConfig
 from repro.train.loop import train_gnn
+from repro.utils import enable_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--full", action="store_true",
@@ -33,6 +34,7 @@ ap.add_argument("--refresh-interval", type=int, default=None,
                 help="enable the online cache manager: drift check + "
                      "adaptive cache refresh every N steps")
 args = ap.parse_args()
+enable_compile_cache()
 
 if args.full:
     n, hidden, steps, batch = 200_000, 6912, args.steps or 300, 512

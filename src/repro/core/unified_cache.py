@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+from functools import partial
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -235,6 +236,7 @@ class CliqueCache:
         self._sharded_arrays = None
         self._prev_sharded_arrays = None
         self._shard_routing = None
+        self._shard_sharding = None  # set by place_shards
         self._prev_epoch = -1
         # guards the lazy materializations below: with the prefetch worker
         # *pool*, several devices of one clique can race the first spec
@@ -320,10 +322,10 @@ class CliqueCache:
     # ---- device residency ----
     @staticmethod
     def _lane_padded(D: int) -> int:
-        """Feature columns padded to the 128-lane boundary (only when
-        feat_dim exceeds one lane tile) — shared by the flat table and the
-        shard stack so the Pallas gather never re-pads per batch."""
-        return D if not (D > 128 and D % 128) else D + 128 - D % 128
+        """Feature columns padded to the 128-lane boundary — shared by the
+        flat table and the shard stack so the Pallas row DMAs (which move
+        whole lane tiles) never re-pad per batch."""
+        return -(-max(D, 1) // 128) * 128
 
     def _epoch_view(self, current, prev, epoch: Optional[int], what: str):
         """Double-buffered epoch pinning, shared by the flat and sharded
@@ -368,14 +370,16 @@ class CliqueCache:
                     # be silently rewritten.  The topology arrays are
                     # replaced wholesale (never mutated), so aliasing them
                     # is safe.
-                    self._device_arrays = {
+                    arrays = {
                         "feat_cache": jnp.array(fc),
                         "feat_pos": jnp.array(self.feat_pos),
                         "cache_indptr": jnp.asarray(self.cache_indptr),
                         "cache_indices": jnp.asarray(self.cache_indices),
                         "topo_pos": jnp.asarray(self.topo_pos),
                     }
-                    self._device_arrays.update(self._topo_shard_jnp())
+                    arrays.update(self._topo_shard_jnp())
+                    # publish whole: readers test for None without the lock
+                    self._device_arrays = arrays
         return self._epoch_view(self._device_arrays,
                                 self._prev_device_arrays, epoch, "")
 
@@ -429,14 +433,34 @@ class CliqueCache:
         return int(np.bincount(self.feat_owner,
                                minlength=len(self.devices)).max())
 
+    def place_shards(self, devices: Sequence) -> None:
+        """Pin where ``sharded_device_arrays`` puts the shard stack: one
+        jax device per clique device, in clique-local order (the clique's
+        row of the hierarchical mesh).  The stack is then sharded along its
+        leading axis, so each device holds its own partition and nothing
+        more.  Call before the first spec build of a run; unplaced caches
+        keep the stack on the default device."""
+        from jax.sharding import Mesh, NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import CLIQUE_AXIS
+
+        if len(devices) != len(self.devices):
+            raise ValueError(f"place_shards: {len(devices)} jax devices for "
+                             f"a {len(self.devices)}-device clique")
+        sharding = NamedSharding(Mesh(np.asarray(list(devices)),
+                                      (CLIQUE_AXIS,)), P(CLIQUE_AXIS))
+        if sharding != self._shard_sharding:
+            self._shard_sharding = sharding
+            self._sharded_arrays = None  # rebuilt on the new devices
+
     def sharded_device_arrays(self, epoch: Optional[int] = None):
         """The cache's *partitioned* device residency: the feature table
-        restacked as one shard per clique device, shape
-        ``(k_g, R, D_padded)`` — row ``local_slot[s]`` of shard
-        ``owner[s]`` is global slot ``s``.  Under the clique mesh the
-        leading axis is sharded, so each device holds exactly the rows the
-        CSLP plan assigned to it, and ``routed_gather`` serves local hits
-        from it directly and peer hits via intra-clique exchange.
+        restacked as one shard per clique device, ``{"feat_shards": (k_g,
+        R, D_padded)}`` — row ``local_slot[s]`` of shard ``owner[s]`` is
+        global slot ``s``.  Placed by ``place_shards``, shard ``g`` lives on
+        clique device ``g`` only, which is what ``routed_gather`` serves
+        local hits from (peer hits ride the intra-clique exchange).
 
         Same lazy build + double-buffered epoch pinning as
         ``device_arrays``: specs built before an online refresh finalize
@@ -444,7 +468,7 @@ class CliqueCache:
         if self._sharded_arrays is None:
             with self._mat_lock:
                 if self._sharded_arrays is None:
-                    import jax.numpy as jnp
+                    import jax
 
                     if self.feat_cache is None:
                         raise RuntimeError(
@@ -453,26 +477,15 @@ class CliqueCache:
                             "materialize_caches=True)")
                     k_g = len(self.devices)
                     owner, local = self.shard_routing()
-                    R = self.shard_row_count()
                     fc = self.feat_cache
                     D = fc.shape[1]
-                    Dp = self._lane_padded(D)
-                    shards = np.zeros((k_g, R, Dp), dtype=np.float32)
+                    shards = np.zeros((k_g, self.shard_row_count(),
+                                       self._lane_padded(D)), np.float32)
                     if len(owner):
                         shards[owner, local, :D] = fc
-                    # jnp.array (copy): the numpy staging buffers are
-                    # transient but owner/local derive from feat_owner,
-                    # which refreshes mutate
-                    self._sharded_arrays = {
-                        "feat_shards": jnp.array(shards),
-                        "slot_owner": jnp.array(owner),
-                        "slot_local": jnp.array(local),
-                    }
-                    # topology shard stacks ride the same view: under the
-                    # clique mesh the leading (k_g) axis is sharded, so
-                    # each device holds exactly its own CSR shard and the
-                    # routed neighbor exchange serves peers over ICI
-                    self._sharded_arrays.update(self._topo_shard_jnp())
+                    # one H2D copy per device, of that device's shard only
+                    self._sharded_arrays = {"feat_shards": jax.device_put(
+                        shards, self._shard_sharding)}
         return self._epoch_view(self._sharded_arrays,
                                 self._prev_sharded_arrays, epoch,
                                 " in sharded form")
@@ -548,7 +561,7 @@ class CliqueCache:
         if self._device_arrays is not None:
             import jax.numpy as jnp
 
-            from repro.kernels import ops, ref
+            from repro.kernels import kernel_impl, ops, ref
 
             old = self._device_arrays
             table = old["feat_cache"]
@@ -558,13 +571,9 @@ class CliqueCache:
                 rows = np.pad(rows, ((0, 0), (0, Dp - rows.shape[1])))
             jidx = jnp.asarray(use, jnp.int32)
             jrows = jnp.asarray(rows)
-            if scatter == "auto":
-                import jax
-                scatter = ("pallas" if jax.default_backend() == "tpu"
-                           else "xla")
-            new_table = (ops.scatter_rows(table, jidx, jrows)
-                         if scatter == "pallas"
-                         else ref.scatter_rows(table, jidx, jrows))
+            new_table = (ref.scatter_rows(table, jidx, jrows)
+                         if kernel_impl(scatter) == "xla"
+                         else ops.scatter_rows(table, jidx, jrows))
             new = dict(old)
             new["feat_cache"] = new_table
             new["feat_pos"] = jnp.array(self.feat_pos)  # copy: mirror mutates
@@ -606,13 +615,6 @@ class CliqueCache:
                 new.pop(k, None)
             new.update(self._topo_shard_jnp())
             self._device_arrays = new
-        if self._sharded_arrays is not None:
-            new = dict(self._sharded_arrays)
-            for k in ("topo_owner", "topo_local", "topo_shard_indptr",
-                      "topo_shard_indices"):
-                new.pop(k, None)
-            new.update(self._topo_shard_jnp())
-            self._sharded_arrays = new
 
     def feat_ids_by_device(self) -> List[np.ndarray]:
         """Current per-device cached feature ids (clique-local order) —
@@ -657,35 +659,16 @@ class CliqueCache:
             # from the zero-length adjacency array would be an XLA error)
             return (jnp.full(seeds.shape + (fanout,), -1, jnp.int32),
                     jnp.zeros(seeds.shape, bool))
-        valid = seeds >= 0
-        safe_seed = jnp.where(valid, seeds, 0)
         if rand is not None:
             r = jnp.asarray(rand)
         else:
             r = jax.random.randint(key, (seeds.shape[0], fanout), 0, 1 << 30)
-        if self.topology_mode == "sharded":
-            own = da["topo_owner"][safe_seed]
-            hit = (own >= 0) & valid
-            o = jnp.maximum(own, 0)
-            loc = da["topo_local"][safe_seed]
-            start = da["topo_shard_indptr"][o, loc]
-            deg = da["topo_shard_indptr"][o, loc + 1] - start
-            offs = r % jnp.maximum(deg, 1)[:, None]
-            E = da["topo_shard_indices"].shape[1]
-            idx = jnp.minimum(start[:, None] + offs, E - 1)
-            out = da["topo_shard_indices"][o[:, None], idx].astype(jnp.int32)
-        else:
-            pos = da["topo_pos"][safe_seed]
-            hit = (pos >= 0) & valid
-            safe = jnp.maximum(pos, 0)
-            start = da["cache_indptr"][safe]
-            deg = da["cache_indptr"][safe + 1] - start
-            offs = r % jnp.maximum(deg, 1)[:, None]
-            idx = jnp.minimum(start[:, None] + offs,
-                              max(len(self.cache_indices) - 1, 0))
-            out = da["cache_indices"][idx].astype(jnp.int32)
-        ok = hit & (deg > 0)
-        return jnp.where(ok[:, None], out, -1), hit
+        sharded = self.topology_mode == "sharded"
+        tables = ((da["topo_owner"], da["topo_local"],
+                   da["topo_shard_indptr"], da["topo_shard_indices"])
+                  if sharded else
+                  (da["topo_pos"], da["cache_indptr"], da["cache_indices"]))
+        return _sample_hop()(tables, seeds, r, sharded=sharded)
 
     def device_sample_chain(self, seeds, fanouts: Sequence[int],
                             rands: Sequence[np.ndarray]):
@@ -855,22 +838,81 @@ class CliqueCache:
         reg.gauge("cache.epoch", clique=clique).set(self.epoch)
 
 
+_sample_hop_jit = None  # built on first use (keeps jax import lazy)
+
+
+def _sample_hop():
+    """One hop of ``CliqueCache.device_sample_cached`` as one jitted
+    program (compiled once per frontier shape, not once per eager op):
+    ``(tables, seeds, rand, *, sharded) -> (neighbors, hit)``, where
+    ``tables`` is (owner, local, shard indptr, shard indices) for the
+    sharded layout and (topo_pos, indptr, indices) for the replicated
+    one."""
+    global _sample_hop_jit
+    if _sample_hop_jit is None:
+        import jax
+        import jax.numpy as jnp
+
+        @partial(jax.jit, static_argnames=("sharded",))
+        def sample_hop(tables, seeds, r, *, sharded: bool):
+            valid = seeds >= 0
+            safe_seed = jnp.where(valid, seeds, 0)
+            if sharded:
+                owner, local, indptr, indices = tables
+                own = owner[safe_seed]
+                hit = (own >= 0) & valid
+                o = jnp.maximum(own, 0)
+                loc = local[safe_seed]
+                start = indptr[o, loc]
+                deg = indptr[o, loc + 1] - start
+                offs = r % jnp.maximum(deg, 1)[:, None]
+                idx = jnp.minimum(start[:, None] + offs, indices.shape[1] - 1)
+                out = indices[o[:, None], idx].astype(jnp.int32)
+            else:
+                pos_map, indptr, indices = tables
+                pos = pos_map[safe_seed]
+                hit = (pos >= 0) & valid
+                safe = jnp.maximum(pos, 0)
+                start = indptr[safe]
+                deg = indptr[safe + 1] - start
+                offs = r % jnp.maximum(deg, 1)[:, None]
+                idx = jnp.minimum(start[:, None] + offs, indices.shape[0] - 1)
+                out = indices[idx].astype(jnp.int32)
+            ok = hit & (deg > 0)
+            return jnp.where(ok[:, None], out, -1), hit
+
+        _sample_hop_jit = sample_hop
+    return _sample_hop_jit
+
+
 def stack_hierarchical_shards(caches: Sequence[CliqueCache],
-                              epochs: Sequence[int]):
+                              epochs: Sequence[int], mesh):
     """Stack every clique's partitioned feature residency into the one
     tensor the hierarchical executor shards over the ``("pod", "clique")``
-    mesh: shape ``(K_c, K_g, R_max, D_padded)`` — row ``ci`` is clique
-    ``ci``'s ``sharded_device_arrays(epochs[ci])["feat_shards"]``.
+    ``mesh``: shape ``(K_c, K_g, R_max, D_padded)`` with sharding
+    ``P(POD_AXIS, CLIQUE_AXIS)`` — row ``ci`` is clique ``ci``'s
+    ``sharded_device_arrays(epochs[ci])["feat_shards"]``.
+
+    Each clique's stack must already sit on its mesh row
+    (``CliqueCache.place_shards``), so the stack is assembled from the
+    per-device shards where they lie: no feature row moves between
+    devices, and a step that consumes the result reshards nothing.
 
     Each clique plans its own cache from its own partition hotness, so
-    per-clique row counts differ; shorter stacks zero-pad to the tallest
-    clique's ``R``.  The pad rows are unreachable — every routing entry
-    (``owner``/``local_slot``) indexes within its own clique's real rows.
-    ``epochs`` pins each clique's refresh generation independently (online
-    refreshes fire per clique, so one synchronized step may legitimately
-    combine different epochs across cliques — never within one).
+    per-clique row counts differ; shorter shards zero-pad (on their own
+    device) to the tallest clique's ``R``.  The pad rows are unreachable —
+    every routing entry (``owner``/``local_slot``) indexes within its own
+    clique's real rows.  ``epochs`` pins each clique's refresh generation
+    independently (online refreshes fire per clique, so one synchronized
+    step may legitimately combine different epochs across cliques — never
+    within one).
     """
+    import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import CLIQUE_AXIS, POD_AXIS
 
     if len(caches) != len(epochs):
         raise ValueError(f"{len(caches)} caches but {len(epochs)} epochs")
@@ -881,10 +923,23 @@ def stack_hierarchical_shards(caches: Sequence[CliqueCache],
     stacks = [c.sharded_device_arrays(int(e))["feat_shards"]
               for c, e in zip(caches, epochs)]
     R = max(s.shape[1] for s in stacks)
-    padded = [s if s.shape[1] == R
-              else jnp.pad(s, ((0, 0), (0, R - s.shape[1]), (0, 0)))
-              for s in stacks]
-    return jnp.stack(padded)
+    pieces = []
+    for ci, s in enumerate(stacks):
+        by_dev = {sh.device: sh.data for sh in s.addressable_shards}
+        for gi, dev in enumerate(mesh.devices[ci]):
+            piece = by_dev.get(dev)
+            if piece is None or piece.shape[0] != 1:
+                raise ValueError(
+                    f"clique {ci}'s shard stack is not placed on its mesh "
+                    "row; call CliqueCache.place_shards with the row's "
+                    "devices before building specs")
+            if piece.shape[1] != R:
+                piece = jnp.pad(piece, ((0, 0), (0, R - piece.shape[1]),
+                                        (0, 0)))
+            pieces.append(piece[None])
+    shape = (len(stacks), len(mesh.devices[0]), R, stacks[0].shape[2])
+    return jax.make_array_from_single_device_arrays(
+        shape, NamedSharding(mesh, P(POD_AXIS, CLIQUE_AXIS)), pieces)
 
 
 def plan_cache_contents(g: CSRGraph, k_g: int, cslp_res, cost_plan: dict,

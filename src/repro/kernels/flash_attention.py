@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 NEG_INF = -1e30
 
 
@@ -63,8 +65,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool = None):
     """q/k/v: (BH, S, Dh) with heads pre-flattened into the batch dim."""
+    if interpret is None:
+        interpret = interpret_default()
     BH, Sq, Dh = q.shape
     Sk = k.shape[1]
     block_q = min(block_q, Sq)

@@ -10,44 +10,36 @@ sources in a single dispatch:
 The unfused pipeline dispatched a gather, then patched misses in with a
 full-table ``.at[].set`` copy; fusing them removes the extra table-sized
 copy and halves the dispatches on the per-batch hot path.  Row selection is
-driven by two scalar-prefetched maps — each grid step stages one candidate
-row from *each* source (the unclaimed side redundantly streams its row 0;
-two block-row fetches per output row, budget VMEM accordingly) and the
-kernel body selects between them:
+driven by two index maps, blocked into SMEM a grid step at a time exactly
+like ``gather.py``; each output row is one DMA from whichever source claims
+it:
 
-  ``idx[i]``      cache slot feeding output row ``i`` (< 0: not cached)
   ``miss_inv[i]`` staging row feeding output row ``i`` (< 0: not a miss)
+  ``idx[i]``      cache slot feeding output row ``i`` (< 0: not cached)
 
 Rows where both maps are negative (shape-bucket padding) come back zero.
-Grid: (rows, feature tiles), feature dim tiled to the 128-lane boundary —
-the same layout discipline as ``gather.py``; callers keep ``table`` and
-``miss_rows`` at one lane-padded width so no per-batch re-pad happens.
+Both sources sit in HBM at one lane-padded width — callers keep ``table``
+and ``miss_rows`` that way so no per-batch re-pad happens.
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import LANES, _default_interpret
+from repro.kernels import interpret_default
+from repro.kernels.gather import (ROWS_PER_STEP, gather_block, index_blocks,
+                                  index_spec, lane_pad)
 
 
-def _fused_kernel(idx_ref, inv_ref, table_ref, miss_ref, out_ref):
-    i = pl.program_id(0)
-    hit = idx_ref[i] >= 0
-    fresh = inv_ref[i] >= 0
-    cached = table_ref[...]
-    staged = miss_ref[...]
-    out_ref[...] = jnp.where(
-        fresh, staged, jnp.where(hit, cached, jnp.zeros_like(cached)))
+def _fused_kernel(inv_ref, idx_ref, miss_ref, table_ref, out_ref, sem):
+    gather_block([(inv_ref, miss_ref), (idx_ref, table_ref)], out_ref, sem)
 
 
 def fused_gather_overlay_pallas(table: jax.Array, idx: jax.Array,
                                 miss_rows: jax.Array, miss_inv: jax.Array, *,
-                                block_d: int = LANES,
                                 interpret: Optional[bool] = None) -> jax.Array:
     """``out[i] = miss_rows[miss_inv[i]] if miss_inv[i] >= 0 else
     (table[idx[i]] if idx[i] >= 0 else 0)``.
@@ -59,36 +51,26 @@ def fused_gather_overlay_pallas(table: jax.Array, idx: jax.Array,
     if it ever were.  Returns (B, D).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = interpret_default()
     N, D = table.shape
     if miss_rows.shape[1] != D:
         raise ValueError(f"miss_rows feature dim {miss_rows.shape[1]} != "
                          f"table feature dim {D} (stage at the table's "
                          "lane-padded width)")
-    idx = idx.reshape(-1).astype(jnp.int32)
-    inv = miss_inv.reshape(-1).astype(jnp.int32)
-    B = idx.shape[0]
-    block_d = min(block_d, max(D, 1))
-    Dp = -(-D // block_d) * block_d
-    if Dp != D:
-        table = jnp.pad(table, ((0, 0), (0, Dp - D)))
-        miss_rows = jnp.pad(miss_rows, ((0, 0), (0, Dp - D)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Dp // block_d),
-        in_specs=[
-            pl.BlockSpec((1, block_d),
-                         lambda i, j, idx, inv: (jnp.maximum(idx[i], 0), j)),
-            pl.BlockSpec((1, block_d),
-                         lambda i, j, idx, inv: (jnp.maximum(inv[i], 0), j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, idx, inv: (i, j)),
-    )
-    fn = pl.pallas_call(
+    B = idx.size
+    idx = index_blocks(idx)
+    inv = index_blocks(miss_inv)
+    table = lane_pad(table)
+    miss_rows = lane_pad(miss_rows.astype(table.dtype))
+    Dp = table.shape[1]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
         _fused_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Dp), table.dtype),
+        grid=(idx.shape[0] // ROWS_PER_STEP,),
+        in_specs=[index_spec(), index_spec(), any_spec, any_spec],
+        out_specs=pl.BlockSpec((ROWS_PER_STEP, Dp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((idx.shape[0], Dp), table.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )
-    out = fn(idx, inv, table, miss_rows.astype(table.dtype))
-    return out[:, :D] if Dp != D else out
+    )(inv, idx, miss_rows, table)
+    return out[:B, :D]

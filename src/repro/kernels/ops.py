@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (kernel bodies execute as jax ops —
-the validation mode for this container) and False on TPU (real Mosaic
-lowering).  The wrappers keep the oracle-identical signatures from ref.py.
+``interpret=None`` follows ``kernels.kernel_impl``: kernel bodies execute
+as jax ops on the CPU platform and lower through Mosaic on TPU.  The
+wrappers keep the oracle-identical signatures from ref.py.
 """
 from __future__ import annotations
 
@@ -41,14 +41,14 @@ def scatter_rows(table: jax.Array, idx: jax.Array, rows: jax.Array,
 
 @partial(jax.jit, static_argnames=("interpret",))
 def sage_aggregate(table: jax.Array, idx: jax.Array, weights: jax.Array,
-                   interpret: bool = True):
+                   interpret: bool = None):
     return sage_aggregate_pallas(table, idx, weights, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = None):
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
                                   block_k=block_k, interpret=interpret)
 

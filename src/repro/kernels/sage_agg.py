@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _agg_kernel(idx_ref, w_ref, table_ref, out_ref):
     b = pl.program_id(0)
@@ -30,8 +32,10 @@ def _agg_kernel(idx_ref, w_ref, table_ref, out_ref):
 
 
 def sage_aggregate_pallas(table: jax.Array, idx: jax.Array, weights: jax.Array,
-                          *, interpret: bool = True) -> jax.Array:
+                          *, interpret: bool = None) -> jax.Array:
     """table (N, D); idx (B, F) int32 (neg = pad); weights (B, F) f32."""
+    if interpret is None:
+        interpret = interpret_default()
     N, D = table.shape
     B, F = idx.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -6,12 +6,13 @@ The result is a *new* table — the refresh runtime double-buffers the HBM
 feature cache, so in-flight batches keep gathering from the previous
 buffer while admitted rows land in the next one.
 
-The kernel iterates the *table* rows (grid = (N, feature tiles)) and uses a
-scalar-prefetched inverse map ``inv[r] -> source row in rows (or -1)`` so
-each grid step either DMAs the admitted row or copies the existing one.
-Iterating table-side (rather than scatter-side) keeps the write set dense
-and makes duplicate indices a non-issue (last write would be grid-order
-dependent; the inverse map picks exactly one source per slot).
+The kernel's output aliases the table (``input_output_aliases``); XLA
+copies the table into it first whenever the caller still holds the input,
+which is what keeps the input buffer untouched.  The grid walks the
+*admitted* rows, ``ROWS_PER_STEP`` indices per step blocked into SMEM as
+in ``gather.py``, and each valid index is one HBM-to-HBM row DMA — so the
+work and the SMEM footprint scale with the admissions, never with the
+table.
 """
 from __future__ import annotations
 
@@ -22,64 +23,70 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import LANES, _default_interpret
+from repro.kernels import interpret_default
+from repro.kernels.gather import (ROWS_PER_STEP, index_blocks, index_spec,
+                                  lane_pad)
 
 
-def _scatter_kernel(inv_ref, rows_ref, table_ref, out_ref):
-    i = pl.program_id(0)
-    fresh = inv_ref[i] >= 0
-    new = rows_ref[...]
-    old = table_ref[...]
-    out_ref[...] = jnp.where(fresh, new, old)
+def _scatter_kernel(idx_ref, rows_ref, table_ref, out_ref, sem):
+    del table_ref  # aliased to out_ref
+    base = pl.program_id(0) * ROWS_PER_STEP
+
+    def copy(r, dst):
+        return pltpu.make_async_copy(rows_ref.at[pl.ds(base + r, 1)],
+                                     out_ref.at[pl.ds(dst, 1)], sem)
+
+    def start(r, carry):
+        dst = idx_ref[r]
+
+        @pl.when(dst >= 0)
+        def _():
+            copy(r, dst).start()
+
+        return carry
+
+    def wait(r, carry):
+        @pl.when(idx_ref[r] >= 0)
+        def _():
+            copy(0, 0).wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, ROWS_PER_STEP, start, 0)
+    jax.lax.fori_loop(0, ROWS_PER_STEP, wait, 0)
 
 
 def scatter_rows_pallas(table: jax.Array, idx: jax.Array, rows: jax.Array, *,
-                        block_d: int = LANES,
                         interpret: Optional[bool] = None) -> jax.Array:
     """Functional row scatter: ``out = table; out[idx[i]] = rows[i]``.
 
     table: (N, D); idx: (B,) int (negatives and out-of-range are dropped);
     rows: (B, D).  Indices must be unique among the valid entries — cache
     refreshes write each freed slot exactly once (the manager guarantees
-    this); duplicate valid indices give an unspecified winner.
+    this); a row named twice ends up with unspecified contents.
 
     Returns a new (N, D) array; the input buffer is untouched, which is
     exactly what the double-buffered cache refresh needs.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = interpret_default()
     N, D = table.shape
     idx = idx.reshape(-1).astype(jnp.int32)
     B = idx.shape[0]
     if B == 0 or N == 0:
         return table
-    block_d = min(block_d, max(D, 1))
-    Dp = -(-D // block_d) * block_d
-    if Dp != D:
-        table = jnp.pad(table, ((0, 0), (0, Dp - D)))
-        rows = jnp.pad(rows, ((0, 0), (0, Dp - D)))
-    rows = rows.astype(table.dtype)
-    # inverse map: for each table row, which admitted row (if any) lands
-    # there; invalid indices are routed to a discarded overflow slot
     valid = (idx >= 0) & (idx < N)
-    inv = jnp.full((N + 1,), -1, jnp.int32)
-    inv = inv.at[jnp.where(valid, idx, N)].set(
-        jnp.arange(B, dtype=jnp.int32))[:N]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(N, Dp // block_d),
-        in_specs=[
-            pl.BlockSpec((1, block_d),
-                         lambda i, j, inv: (jnp.maximum(inv[i], 0), j)),
-            pl.BlockSpec((1, block_d), lambda i, j, inv: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, inv: (i, j)),
-    )
-    fn = pl.pallas_call(
+    blocks = index_blocks(jnp.where(valid, idx, -1))
+    tab = lane_pad(table)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
         _scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, Dp), table.dtype),
+        grid=(blocks.shape[0] // ROWS_PER_STEP,),
+        in_specs=[index_spec(), any_spec, any_spec],
+        out_specs=any_spec,
+        out_shape=jax.ShapeDtypeStruct(tab.shape, table.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )
-    out = fn(inv, rows, table)
-    return out[:, :D] if Dp != D else out
+    )(blocks, lane_pad(rows.astype(table.dtype)), tab)
+    return out[:, :D] if tab.shape[1] != D else out

@@ -21,12 +21,7 @@ routed gather's peer exchange never crosses cliques), while gradient
 synchronization additionally reduces over ``"pod"`` (the data-parallel
 inter-clique axis, PCIe/DCN in hardware).  A single-clique plan is the
 degenerate ``K_c=1`` case of the same mesh — there is no separate 1-D
-execution path in the trainer.
-
-Everything here works on both the legacy (``jax.experimental.shard_map``,
-jax 0.4.x) and the current (``jax.shard_map`` / ``AxisType``) APIs —
-``shard_map_compat`` picks whichever the installed jax provides, which is
-what lets the CI matrix span the pinned-min and latest jax releases.
+execution path in the trainer.  Every mesh uses ``AxisType.Auto`` axes.
 """
 from __future__ import annotations
 
@@ -34,22 +29,10 @@ import math
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - legacy jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 CLIQUE_AXIS = "clique"
 POD_AXIS = "pod"
-
-
-def _axis_types(n: int) -> dict:
-    """kwargs for Mesh(): Auto axis types where the API supports them."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -66,7 +49,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     import numpy as np
 
     dev_array = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(dev_array, axes, **_axis_types(len(axes)))
+    return Mesh(dev_array, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
@@ -75,7 +58,7 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
 
     n = math.prod(shape)
     dev_array = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(dev_array, axes, **_axis_types(len(axes)))
+    return Mesh(dev_array, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_clique_mesh(n_devices: Optional[int] = None,
@@ -103,7 +86,7 @@ def make_clique_mesh(n_devices: Optional[int] = None,
                 "importing jax).")
         devices = avail[:n]
     dev_array = np.asarray(list(devices))
-    return Mesh(dev_array, (axis_name,), **_axis_types(1))
+    return Mesh(dev_array, (axis_name,), axis_types=(AxisType.Auto,))
 
 
 def make_hierarchical_mesh(cliques: Sequence[Sequence[int]],
@@ -147,26 +130,5 @@ def make_hierarchical_mesh(cliques: Sequence[Sequence[int]],
             f"make_hierarchical_mesh: {len(devices)} devices pinned for a "
             f"{k_c}x{k_g} mesh (need exactly {n})")
     dev_array = np.asarray(list(devices)).reshape(k_c, k_g)
-    return Mesh(dev_array, tuple(axis_names), **_axis_types(2))
+    return Mesh(dev_array, tuple(axis_names), axis_types=(AxisType.Auto,) * 2)
 
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax generations.
-
-    jax >= 0.5 exposes ``jax.shard_map`` (replication checking via
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (``check_rep``).  Replication checking is disabled on both paths: the
-    clique executor's out-specs mix sharded (batch) and replicated
-    (psum-reduced grads) outputs, which the static checkers reject.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # pragma: no cover - transitional releases
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)

@@ -338,10 +338,11 @@ class DeviceBatchBuilder(BatchBuilder):
 
     ``gather`` picks the cached-row gather implementation:
       * ``"pallas"`` — the Mosaic kernels (`fused_batch` / `gather_rows`);
-        compiled on TPU, interpreted elsewhere (slow off-TPU, but the real
-        hot path).
+        compiled on TPU, interpreted on CPU (slow there, but the real hot
+        path).
       * ``"xla"``    — the jnp oracles with identical semantics.
-      * ``"auto"``   — pallas on TPU, xla otherwise (default).
+      * ``"auto"``   — pallas on TPU, xla otherwise (default; the one
+        switch is ``repro.kernels.kernel_impl``).
 
     ``bucket`` sets the shape quantum of the spec layout (see module doc);
     ``fused=False`` falls back to the legacy finalize chain (separate
@@ -361,11 +362,9 @@ class DeviceBatchBuilder(BatchBuilder):
             raise ValueError("DeviceBatchBuilder needs a unified cache "
                              "(build a LegionPlan, or use backend='host')")
         super().__init__(g, cache, fanouts, counter, dev, observer)
-        if gather not in ("auto", "pallas", "xla"):
-            raise ValueError(f"unknown gather impl {gather!r}")
-        if gather == "auto":
-            import jax
-            gather = "pallas" if jax.default_backend() == "tpu" else "xla"
+        from repro.kernels import kernel_impl
+
+        gather = "xla" if kernel_impl(gather) == "xla" else "pallas"
         if sampler not in ("chain", "stepwise"):
             raise ValueError(f"unknown sampler mode {sampler!r}")
         if bucket < 1:
@@ -456,28 +455,33 @@ class DeviceBatchBuilder(BatchBuilder):
             return jnp.zeros((1, self._staging_width()), jnp.float32)
         return self.cache.device_arrays(epoch)["feat_cache"]
 
+    def finalize_args(self, spec) -> tuple:
+        """The fused finalize's positional arguments for ``spec``: the
+        epoch-pinned table, the staged miss rows (uploaded here, after
+        which the spec's staging buffer is back in the pool) and the
+        int32 maps.  ``_get_fused_finalize()(*args, impl=..., D=...)``."""
+        import jax.numpy as jnp
+
+        table = self._table(spec.cache_epoch)
+        # jnp.array copies, but the copy is DISPATCHED, not done: the
+        # transfer must complete before the staging buffer goes back to
+        # the pool, or the next fill overwrites it mid-read
+        with maybe_span(self.telemetry, "h2d_staging", dev=self.dev,
+                        rows=spec.n_miss):
+            miss = jnp.array(spec.miss_feats)
+            miss.block_until_ready()
+        self.release_spec(spec)
+        idx = spec.cache_pos.astype(np.int32)  # -1 at miss AND pad rows
+        pos = tuple(np.ascontiguousarray(p.reshape(-1).astype(np.int32))
+                    for p in spec.level_pos)
+        valid = tuple(lvl >= 0 for lvl in spec.levels)
+        return table, idx, miss, spec.miss_inv, spec.labels, pos, valid
+
     def finalize(self, spec):
         if not self.fused:
             return self._finalize_unfused(spec)
-        import jax.numpy as jnp
-
-        tele = self.telemetry
-        with maybe_span(tele, "finalize", dev=self.dev):
-            table = self._table(spec.cache_epoch)
-            # jnp.array copies, but the copy is DISPATCHED, not done: the
-            # transfer must complete before the staging buffer goes back to
-            # the pool, or the next fill overwrites it mid-read
-            with maybe_span(tele, "h2d_staging", dev=self.dev,
-                            rows=spec.n_miss):
-                miss = jnp.array(spec.miss_feats)
-                miss.block_until_ready()
-            self.release_spec(spec)
-            idx = spec.cache_pos.astype(np.int32)  # -1 at miss AND pad rows
-            pos = tuple(np.ascontiguousarray(p.reshape(-1).astype(np.int32))
-                        for p in spec.level_pos)
-            valid = tuple(lvl >= 0 for lvl in spec.levels)
-            return _get_fused_finalize()(table, idx, miss, spec.miss_inv,
-                                         spec.labels, pos, valid,
+        with maybe_span(self.telemetry, "finalize", dev=self.dev):
+            return _get_fused_finalize()(*self.finalize_args(spec),
                                          impl=self.gather, D=self.g.feat_dim)
 
     # -- legacy (pre-fused) finalize: the benchmark's *before* arm --------

@@ -58,6 +58,10 @@ _PIPE_FOLD_KEYS = ("batches_built", "gets", "host_build_s_total",
                    "worker_deaths", "worker_restarts")
 _REFRESH_FOLD_KEYS = ("checks", "refreshes", "admitted", "evicted",
                       "topo_rebuilds", "refresh_bytes_h2d")
+# a fresh pipeline's first build compiles the device sampler (tens of
+# seconds per hop shape on a TPU at the paper's batch), so the first fetch
+# waits longer than a steady-state one; a dead worker still surfaces at once
+_FIRST_GET_TIMEOUT_S = 900.0
 
 
 def _fold(base: dict, summary: dict, keys: Sequence[str]) -> None:
@@ -98,7 +102,6 @@ def _make_sharded_step(cfg: GNNConfig, opt, mesh, axes, n_total: int,
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.gather import routed_gather
-    from repro.launch.mesh import shard_map_compat
 
     D = feat_dim
     pod_axis, clique_axis = axes
@@ -139,8 +142,10 @@ def _make_sharded_step(cfg: GNNConfig, opt, mesh, axes, n_total: int,
                              jax.lax.psum(grads, axes))
         return grads, loss, acc
 
-    smapped = shard_map_compat(body, mesh, in_specs=(P(), P2, P2),
-                               out_specs=(P(), P(), P()))
+    # replication checking off: the out-specs mix psum-reduced (replicated)
+    # values with per-shard inputs, which the static checker rejects
+    smapped = jax.shard_map(body, mesh=mesh, in_specs=(P(), P2, P2),
+                            out_specs=(P(), P(), P()), check_vma=False)
 
     @jax.jit
     def step(params, opt_state, shards, packed):
@@ -492,6 +497,10 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
             exec_cl = st["exec_cliques"]
             clique_caches = [plan_l.caches[ci] for ci in exec_clique_ids]
             hier_mesh = make_hierarchical_mesh(exec_cl)
+            # each clique's shard stack lives on its own mesh row, placed
+            # once per cache epoch; steps then move no cache rows
+            for ci, cache in enumerate(clique_caches):
+                cache.place_shards(list(hier_mesh.devices[ci]))
             sharded_step = _make_sharded_step(
                 cfg, opt, hier_mesh, (POD_AXIS, CLIQUE_AXIS),
                 n_total=per_dev * len(devs), feat_dim=g.feat_dim,
@@ -504,18 +513,18 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
                 the stack rebuilds only when some clique's epoch moves.
                 Two entries are retained — the same double-buffer horizon
                 as the caches — so queued steps straddling a refresh keep
-                their stack alive.  A rebuild is one device-side restack
-                (the per-clique inputs are already HBM-resident and
-                epoch-memoized per cache; only the refreshed clique's
-                shards crossed PCIe), paid once per refresh *event*,
-                never per step; an in-place row update cannot do better
-                here because R_max may change when a refresh re-homes
-                slot owners."""
+                their stack alive.  A rebuild assembles the per-device
+                shards where they already lie (each clique's stack is
+                HBM-resident on its mesh row and epoch-memoized per cache;
+                only the refreshed clique's shards crossed PCIe), paid once
+                per refresh *event*, never per step; an in-place row update
+                cannot do better here because R_max may change when a
+                refresh re-homes slot owners."""
                 if epochs not in shard_stack_memo:
                     while len(shard_stack_memo) >= 2:
                         shard_stack_memo.pop(next(iter(shard_stack_memo)))
                     shard_stack_memo[epochs] = stack_hierarchical_shards(
-                        clique_caches, epochs)
+                        clique_caches, epochs, hier_mesh)
                 return shard_stack_memo[epochs]
         st["sharded_step"] = sharded_step
 
@@ -717,8 +726,8 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
         # workers), so it gets its own span; train_loop is the
         # steady-state stepping loop that device_step spans tile.
         with maybe_span(tele, "pipeline_prime"):
-            next_batch = (st["finalize"](st["prefetcher"].get())
-                          if steps > step0 else None)
+            next_batch = (st["finalize"](st["prefetcher"].get(
+                _FIRST_GET_TIMEOUT_S)) if steps > step0 else None)
         with maybe_span(tele, "train_loop"):
             for step in range(step0, steps):
                 if fplan is not None:
@@ -731,7 +740,8 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
                         # the in-flight batch was built by the lost
                         # topology: discard it, remesh, rebuild step
                         remesh(dead, step)
-                        next_batch = st["finalize"](st["prefetcher"].get())
+                        next_batch = st["finalize"](st["prefetcher"].get(
+                            _FIRST_GET_TIMEOUT_S))
                 t0 = time.perf_counter()
                 # the device-step span covers dispatch, the overlapped
                 # prefetch of step i+1, and the block on step i's loss —

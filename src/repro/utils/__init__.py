@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import json
 import logging
+import os
 import time
 from typing import Any
 
@@ -15,6 +16,25 @@ if not logger.handlers:
     _h.setFormatter(logging.Formatter("[%(asctime)s %(levelname)s] %(message)s", "%H:%M:%S"))
     logger.addHandler(_h)
     logger.setLevel(logging.INFO)
+
+
+# the checkout root: the directory that holds src/repro/utils/__init__.py
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at a fixed place and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, wins
+    and nothing is set here (JAX reads the variable itself); otherwise the
+    cache lives in ``<checkout>/.jax_cache``, which git ignores.  The path
+    never varies between runs, since it is part of each entry's key."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_size_bytes(tree: Any) -> int:

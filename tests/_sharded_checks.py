@@ -23,7 +23,7 @@ def check_routed_gather():
 
     from repro.kernels import ref
     from repro.kernels.gather import routed_gather
-    from repro.launch.mesh import make_clique_mesh, shard_map_compat
+    from repro.launch.mesh import make_clique_mesh
 
     rng = np.random.default_rng(0)
     k, R, D, n = N_DEV, 12, 32, 50
@@ -35,11 +35,11 @@ def check_routed_gather():
 
     mesh = make_clique_mesh(k)
     for impl in ("xla", "pallas"):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda s, o, l: routed_gather(s[0], o[0], l[0], "clique",
                                           impl=impl)[None],
-            mesh, in_specs=(P("clique"), P("clique"), P("clique")),
-            out_specs=P("clique"))
+            mesh=mesh, in_specs=(P("clique"), P("clique"), P("clique")),
+            out_specs=P("clique"), check_vma=False)
         got = np.asarray(jax.jit(fn)(shards, owner, local))
         np.testing.assert_array_equal(got, want, err_msg=f"impl={impl}")
     print("routed gather OK")
@@ -47,8 +47,8 @@ def check_routed_gather():
 
 def check_routed_neighbor_exchange():
     """shard_map routed neighbor exchange == dense oracle == host sampler
-    (replayed draws), xla and pallas impls — the mesh-collective form of
-    the sharded topology cache's sample path."""
+    (replayed draws) — the mesh-collective form of the sharded topology
+    cache's sample path."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -59,7 +59,7 @@ def check_routed_neighbor_exchange():
     from repro.graph.sampling import host_sample_level
     from repro.kernels import ref
     from repro.kernels.gather import routed_neighbor_sample
-    from repro.launch.mesh import make_clique_mesh, shard_map_compat
+    from repro.launch.mesh import make_clique_mesh
 
     rng = np.random.default_rng(1)
     g = powerlaw_graph(3000, 8, seed=9, feat_dim=16)
@@ -87,15 +87,14 @@ def check_routed_neighbor_exchange():
         assert (want[gi][~hit] == -1).all()
 
     mesh = make_clique_mesh(k)
-    for impl in ("xla", "pallas"):
-        fn = shard_map_compat(
-            lambda p, i, o, l, r: routed_neighbor_sample(
-                p[0], i[0], o[0], l[0], r[0], "clique", impl=impl)[None],
-            mesh, in_specs=(P("clique"), P("clique"), P("clique"),
-                            P("clique"), P("clique")),
-            out_specs=P("clique"))
-        got = np.asarray(jax.jit(fn)(indptr, indices, owner, local, rand))
-        np.testing.assert_array_equal(got, want, err_msg=f"impl={impl}")
+    fn = jax.shard_map(
+        lambda p, i, o, l, r: routed_neighbor_sample(
+            p[0], i[0], o[0], l[0], r[0], "clique")[None],
+        mesh=mesh, in_specs=(P("clique"), P("clique"), P("clique"),
+                             P("clique"), P("clique")),
+        out_specs=P("clique"), check_vma=False)
+    got = np.asarray(jax.jit(fn)(indptr, indices, owner, local, rand))
+    np.testing.assert_array_equal(got, want)
     print("routed neighbor exchange OK")
 
 

@@ -150,11 +150,6 @@ SCRIPT = textwrap.dedent("""
 
 
 def test_multidevice_suite(tmp_path):
-    import jax.sharding
-    import pytest
-    if not hasattr(jax.sharding, "AxisType"):
-        pytest.skip("needs jax explicit-sharding APIs (AxisType/set_mesh, "
-                    "jax>=0.5); container jax is older")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     script = tmp_path / "multidev.py"
     script.write_text(SCRIPT)
