@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graph.csr import CSRGraph
+from repro.obs import maybe_span
 
 
 def host_sample_level(g: CSRGraph, seeds: np.ndarray, fanout: int,
@@ -131,7 +132,8 @@ def _mirror_sample_level(cache, seeds: np.ndarray, fanout: int,
 
 
 def cache_sample_dispatch(g: CSRGraph, cache, seeds: np.ndarray,
-                          fanouts: Sequence[int], rng: np.random.Generator):
+                          fanouts: Sequence[int], rng: np.random.Generator,
+                          telemetry=None):
     """Phase 1 of the chained cache-aware sampler: draw every hop's
     randomness in host-sampler order and enqueue the whole device chain
     (``CliqueCache.device_sample_chain`` — the routed neighbor exchange
@@ -157,7 +159,12 @@ def cache_sample_dispatch(g: CSRGraph, cache, seeds: np.ndarray,
     masks match the per-hop reference path exactly.  ``counter`` (a
     ``TrafficCounter``) gets ``host_sample_syncs += 1`` iff the batch
     touched the host CSR at all — a warm epoch whose frontier fits the
-    cached topology resolves with zero host sampling syncs.
+    cached topology resolves with zero host sampling syncs.  With
+    ``telemetry`` (a ``repro.obs.Telemetry``) the resolve's sync is the
+    ``sample_sync`` span (attr ``rows``, the frontier rows read back) and
+    the repair pass the ``sample_repair`` span (attrs ``mirror_rows`` and
+    ``host_rows``, the rows replayed from the mirror and from the host
+    CSR).
     """
     seeds = np.asarray(seeds, dtype=np.int64)
     rands = []
@@ -173,40 +180,49 @@ def cache_sample_dispatch(g: CSRGraph, cache, seeds: np.ndarray,
         frontier = seeds
         shape = (len(frontier),)
         # one sync for the whole chain
-        outs = [np.asarray(o) for o in dev_outs]
-        dhits = [np.asarray(h) for h in dev_hits]
+        with maybe_span(telemetry, "sample_sync") as sp:
+            outs = [np.asarray(o) for o in dev_outs]
+            dhits = [np.asarray(h) for h in dev_hits]
+            if sp is not None:
+                sp.attrs["rows"] = sum(len(o) for o in outs)
         mirror_ok = cache.cache_indices is not None
         ok = np.ones(len(frontier), dtype=bool)
-        touched_host = False
-        for k, f in enumerate(fanouts):
-            flat = frontier.reshape(-1)
-            resolved = dhits[k] & ok
-            out = outs[k].astype(np.int64)
-            need = np.flatnonzero(~resolved)
-            if len(need):
-                src = flat[need]
-                neg = src < 0
-                out[need[neg]] = -1
-                live = need[~neg]
-                if len(live):
-                    cached = (cache.topo_pos[flat[live]] >= 0) if mirror_ok \
-                        else np.zeros(len(live), dtype=bool)
-                    fix = live[cached]
-                    if len(fix):
-                        out[fix] = _mirror_sample_level(cache, flat[fix], f,
-                                                        rands[k][fix])
-                        resolved[fix] = True
-                    host = live[~cached]
-                    if len(host):
-                        touched_host = True
-                        out[host] = host_sample_level(g, flat[host], f, rng,
-                                                      rand=rands[k][host])
-            hits.append(resolved)
-            shape = shape + (f,)
-            levels.append(out.reshape(shape))
-            frontier = levels[-1]
-            ok = np.repeat(resolved, f)
-        if counter is not None and touched_host:
+        n_mirror = n_host = 0
+        with maybe_span(telemetry, "sample_repair") as sp:
+            for k, f in enumerate(fanouts):
+                flat = frontier.reshape(-1)
+                resolved = dhits[k] & ok
+                out = outs[k].astype(np.int64)
+                need = np.flatnonzero(~resolved)
+                if len(need):
+                    src = flat[need]
+                    neg = src < 0
+                    out[need[neg]] = -1
+                    live = need[~neg]
+                    if len(live):
+                        cached = ((cache.topo_pos[flat[live]] >= 0)
+                                  if mirror_ok
+                                  else np.zeros(len(live), dtype=bool))
+                        fix = live[cached]
+                        if len(fix):
+                            out[fix] = _mirror_sample_level(
+                                cache, flat[fix], f, rands[k][fix])
+                            resolved[fix] = True
+                            n_mirror += len(fix)
+                        host = live[~cached]
+                        if len(host):
+                            out[host] = host_sample_level(
+                                g, flat[host], f, rng, rand=rands[k][host])
+                            n_host += len(host)
+                hits.append(resolved)
+                shape = shape + (f,)
+                levels.append(out.reshape(shape))
+                frontier = levels[-1]
+                ok = np.repeat(resolved, f)
+            if sp is not None:
+                sp.attrs["mirror_rows"] = n_mirror
+                sp.attrs["host_rows"] = n_host
+        if counter is not None and n_host:
             with counter.lock:
                 counter.host_sample_syncs += 1
         return levels, hits

@@ -4,8 +4,9 @@ Reads one schema-v1 JSONL stream (validating every line) and prints the
 story a human needs from a training run:
 
 * throughput — steps, wall time, steps/s from the device-step spans;
-* where the time went — per-span-name totals/means and share of wall,
-  with the queue-dry (device-stall) time called out;
+* where the time went — per-span-name totals/means, self time (less the
+  spans nested inside on the same thread) and share of wall, with the
+  queue-dry (device-stall) time called out;
 * cache behavior over time — per-window feature/topology hit rates and
   local/peer/PCIe byte deltas from the snapshots;
 * refresh activity — online cache-manager counters, when present.
@@ -48,6 +49,33 @@ def load_stream(path: str) -> List[dict]:
     return lines
 
 
+# slack for the microsecond floats of a stream when testing containment
+_EPS_US = 1e-3
+
+
+def self_times(spans: List[dict]) -> Dict[str, float]:
+    """Per span name, the summed self time in seconds: each span's
+    duration less those of its direct children, the spans it contains on
+    the same thread (spans nest strictly on a thread)."""
+    out: Dict[str, float] = {}
+    threads: Dict[tuple, List[dict]] = {}
+    for s in spans:
+        threads.setdefault((s["tid"], s["thread"]), []).append(s)
+    for own in threads.values():
+        own.sort(key=lambda s: (s["ts_us"], -s["dur_us"]))
+        open_: List[tuple] = []  # (end_us, name) of the enclosing spans
+        for s in own:
+            end = s["ts_us"] + s["dur_us"]
+            while open_ and open_[-1][0] + _EPS_US < end:
+                open_.pop()
+            if open_:
+                parent = open_[-1][1]
+                out[parent] = out.get(parent, 0.0) - s["dur_us"] / 1e6
+            out[s["name"]] = out.get(s["name"], 0.0) + s["dur_us"] / 1e6
+            open_.append((end, s["name"]))
+    return out
+
+
 def digest(lines: List[dict]) -> dict:
     """Fold a validated stream into the report's numbers."""
     meta = lines[0]
@@ -61,6 +89,8 @@ def digest(lines: List[dict]) -> dict:
         agg["count"] += 1
         agg["total_s"] += s["dur_us"] / 1e6
         agg["max_s"] = max(agg["max_s"], s["dur_us"] / 1e6)
+    for name, self_s in self_times(spans).items():
+        by_name[name]["self_s"] = self_s
     for agg in by_name.values():
         agg["mean_s"] = agg["total_s"] / max(agg["count"], 1)
 
@@ -157,13 +187,14 @@ def print_report(d: dict, out=None) -> None:
           f"{d['queue_dry_s']:.3f} s = {stall_pct:.1f}% of the loop\n\n")
     w("where the time went (per span name):\n")
     w(f"  {'span':<18}{'count':>7}{'total s':>10}{'mean ms':>10}"
-      f"{'max ms':>10}{'% wall':>8}\n")
+      f"{'self ms':>10}{'max ms':>10}{'% wall':>8}\n")
     for name, a in sorted(d["spans"].items(),
                           key=lambda kv: -kv[1]["total_s"]):
         pct = 100 * a["total_s"] / max(d["wall_s"], 1e-9)
+        self_ms = 1e3 * a["self_s"] / max(a["count"], 1)
         w(f"  {name:<18}{a['count']:>7}{a['total_s']:>10.3f}"
-          f"{1e3 * a['mean_s']:>10.3f}{1e3 * a['max_s']:>10.3f}"
-          f"{pct:>8.1f}\n")
+          f"{1e3 * a['mean_s']:>10.3f}{self_ms:>10.3f}"
+          f"{1e3 * a['max_s']:>10.3f}{pct:>8.1f}\n")
     if d["windows"]:
         w("\ncache/traffic windows (hit %, byte deltas):\n")
         w(f"  {'steps':<12}{'feat%':>6}{'topo%':>6}{'local MB':>11}"

@@ -7,8 +7,14 @@ On exit it reports one completed record — name, wall-clock interval
 step and attributes — to the recorder (the Telemetry object), which fans
 it out to the JSONL and Chrome-trace sinks.  Emitting only *completed*
 spans keeps every line a balanced begin/end pair by construction; the
-tracer still tracks per-thread open-span depth so shutdown can assert
-nothing was left dangling.
+tracer still keeps a per-thread stack of open spans so shutdown can
+assert nothing was left dangling.
+
+Step inheritance: a span opened without ``step=`` takes the step of the
+innermost span open on its thread (None if there is none), so a leaf
+inside ``spec_build(step=i)`` carries ``i`` without the code it wraps
+knowing the step.  A span may also set ``step`` itself before it exits
+(``prefetch_get`` learns its batch's step only when the batch arrives).
 
 The jax bridge wraps the same interval in a ``TraceAnnotation`` so the
 span shows up inside an XLA profiler trace (``jax.profiler.trace``)
@@ -60,7 +66,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         if self._tracker is not None:
-            self._tracker.push()
+            if self.step is None:
+                self.step = self._tracker.current_step()
+            self._tracker.push(self.step)
         if self._jax:
             cls = _trace_annotation_cls()
             if cls is not None:
@@ -82,22 +90,33 @@ class Span:
 
 
 class OpenSpanTracker:
-    """Per-thread open-span depth — the balance check behind the
-    'no dangling spans at shutdown' assertion and the nesting tests."""
+    """Per-thread stack of the open spans' steps — the balance check
+    behind the 'no dangling spans at shutdown' assertion, and the source
+    of the step a span without one inherits."""
 
     def __init__(self):
         self._local = threading.local()
         self._lock = threading.Lock()
         self._open_total = 0
 
-    def push(self) -> None:
-        depth = getattr(self._local, "depth", 0)
-        self._local.depth = depth + 1
+    def _stack(self) -> list:
+        st = getattr(self._local, "steps", None)
+        if st is None:
+            st = self._local.steps = []
+        return st
+
+    def current_step(self) -> Optional[int]:
+        """The step of the innermost span open on this thread."""
+        st = self._stack()
+        return st[-1] if st else None
+
+    def push(self, step: Optional[int] = None) -> None:
+        self._stack().append(step)
         with self._lock:
             self._open_total += 1
 
     def pop(self) -> None:
-        self._local.depth = getattr(self._local, "depth", 1) - 1
+        self._stack().pop()
         with self._lock:
             self._open_total -= 1
 

@@ -115,6 +115,9 @@ class BatchSpec:
     # CliqueCache.shard_routing at spec-build time
     owner: Optional[np.ndarray] = None
     local_slot: Optional[np.ndarray] = None
+    # the training step this spec was filled for (``fill_spec(step=)``);
+    # the consumer's finalize spans carry it
+    step: Optional[int] = None
 
 
 class _StagingPool:
@@ -212,8 +215,9 @@ class BatchBuilder:
         # one changes neither batches nor traffic accounting
         self.observer = observer
         # telemetry tap (repro.obs.Telemetry), attached by the train loop:
-        # finalize/H2D-staging spans when set, a shared no-op context when
-        # None — never perturbs batches or accounting
+        # spans over the build's stages and over finalize/H2D staging when
+        # set, a shared no-op context when None — never perturbs batches
+        # or accounting
         self.telemetry = None
         # tiered feature store (core.feature_store.FeatureStore), attached
         # by the train loop: when set, HBM-miss fills route through its
@@ -252,9 +256,9 @@ class BatchBuilder:
         _, hit = self.cache.split_hits(ids)
         return ids[~hit]
 
-    def build_spec(self, seeds: np.ndarray,
-                   rng: np.random.Generator) -> BatchSpec:
-        return self.fill_spec(self.sample_spec(seeds, rng))
+    def build_spec(self, seeds: np.ndarray, rng: np.random.Generator,
+                   step: Optional[int] = None) -> BatchSpec:
+        return self.fill_spec(self.sample_spec(seeds, rng), step=step)
 
     def _store_fill(self, ids: np.ndarray,
                     step: Optional[int]) -> np.ndarray:
@@ -310,6 +314,7 @@ class HostBatchBuilder(BatchBuilder):
             self.cache.extract_features(ids, self.dev, self.counter,
                                         store=self.store, step=step)
             if self.cache is not None else self._store_fill(ids, step))
+        spec.step = step
         return spec
 
     @staticmethod
@@ -327,7 +332,8 @@ class HostBatchBuilder(BatchBuilder):
     def finalize(self, spec):
         import jax.numpy as jnp
 
-        with maybe_span(self.telemetry, "finalize", dev=self.dev):
+        with maybe_span(self.telemetry, "finalize", step=spec.step,
+                        dev=self.dev):
             return {k: jnp.asarray(v)
                     for k, v in self.assemble(spec).items()}
 
@@ -382,57 +388,79 @@ class DeviceBatchBuilder(BatchBuilder):
         return CliqueCache._lane_padded(self.g.feat_dim)
 
     def sample_spec(self, seeds, rng):
+        # spans (with telemetry): sample_dispatch, sample_sync and
+        # sample_repair (inside resolve), sample_account, spec_dedup; the
+        # step they carry is the enclosing spec_build's
+        tele = self.telemetry
         if self.sampler == "chain":
             # dispatch the whole device chain, then fetch labels while it
             # is in flight; resolve() pays the single sync and repairs
             # stale-parent / host-miss rows (see cache_sample_dispatch)
-            resolve = cache_sample_dispatch(self.g, self.cache, seeds,
-                                            self.fanouts, rng)
-            labels = self.g.get_labels(seeds)
+            with maybe_span(tele, "sample_dispatch") as sp:
+                resolve = cache_sample_dispatch(self.g, self.cache, seeds,
+                                                self.fanouts, rng,
+                                                telemetry=tele)
+                labels = self.g.get_labels(seeds)
+                if sp is not None:
+                    sp.attrs["draws"] = int(
+                        len(seeds) * np.cumprod(self.fanouts).sum())
             levels, _topo_hits = resolve(counter=self.counter)
         else:
             levels, _topo_hits = cache_sample_batch(
                 self.g, self.cache, seeds, self.fanouts, rng, chain=False,
                 counter=self.counter)
             labels = self.g.get_labels(seeds)
-        self._account_sampling(levels)
-        ids = unique_vertices(levels)
+        with maybe_span(tele, "sample_account"):
+            self._account_sampling(levels)
+        with maybe_span(tele, "spec_dedup") as sp:
+            ids = unique_vertices(levels)
+            level_pos = _level_positions(ids, levels)
+            if sp is not None:
+                sp.attrs["n_ids"] = len(ids)
         return BatchSpec(labels=labels, levels=levels, ids=ids,
-                         level_pos=_level_positions(ids, levels),
-                         n_ids=len(ids))
+                         level_pos=level_pos, n_ids=len(ids))
 
     def fill_spec(self, spec, step=None):
         # the hit/miss split runs HERE — at build time, after any refresh
         # hook the step barrier serialized before it — so the spec pins the
-        # *current* cache epoch regardless of how far ahead it was sampled
+        # *current* cache epoch regardless of how far ahead it was sampled.
+        # Spans (with telemetry): fill_split, then fill_miss.
+        tele = self.telemetry
         ids, n_ids = spec.ids, spec.n_ids
-        cache_pos, hit = self.cache.split_hits(ids)
-        if self.counter is not None:
-            self.cache.account_feature_gather(cache_pos, hit, self.dev,
-                                              self.counter)
-        if self.store is not None:
-            self.store.record_hbm(n_ids, int(hit.sum()))
-        n_miss = int((~hit).sum())
-        # bucket-rounded layout: pad rows are inert (-1 / False) and never
-        # referenced by level_pos, so every downstream shape is stable
-        n_pad = _round_bucket(n_ids, self.bucket)
-        m_pad = _round_bucket(n_miss, self.bucket)
-        ids_p = np.full(n_pad, -1, dtype=np.int64)
-        ids_p[:n_ids] = ids
-        pos_p = np.full(n_pad, -1, dtype=np.int64)
-        pos_p[:n_ids] = cache_pos
-        hit_p = np.zeros(n_pad, dtype=bool)
-        hit_p[:n_ids] = hit
-        miss_inv = np.full(n_pad, -1, dtype=np.int32)
-        miss_inv[np.flatnonzero(~hit)] = np.arange(n_miss, dtype=np.int32)
-        staging = self._staging.acquire(m_pad, self._staging_width())
-        D = self.g.feat_dim
-        if n_miss:
-            miss_ids = ids[~hit]
-            staging[:n_miss, :D] = (
-                self.store.gather(miss_ids, step=step, dev=self.dev)
-                if self.store is not None else self.g.get_features(miss_ids))
-        staging[n_miss:, :D] = 0.0
+        with maybe_span(tele, "fill_split") as sp:
+            cache_pos, hit = self.cache.split_hits(ids)
+            if self.counter is not None:
+                self.cache.account_feature_gather(cache_pos, hit, self.dev,
+                                                  self.counter)
+            if self.store is not None:
+                self.store.record_hbm(n_ids, int(hit.sum()))
+            n_miss = int((~hit).sum())
+            # bucket-rounded layout: pad rows are inert (-1 / False) and
+            # never referenced by level_pos, so every downstream shape is
+            # stable
+            n_pad = _round_bucket(n_ids, self.bucket)
+            m_pad = _round_bucket(n_miss, self.bucket)
+            ids_p = np.full(n_pad, -1, dtype=np.int64)
+            ids_p[:n_ids] = ids
+            pos_p = np.full(n_pad, -1, dtype=np.int64)
+            pos_p[:n_ids] = cache_pos
+            hit_p = np.zeros(n_pad, dtype=bool)
+            hit_p[:n_ids] = hit
+            miss_inv = np.full(n_pad, -1, dtype=np.int32)
+            miss_inv[np.flatnonzero(~hit)] = np.arange(n_miss,
+                                                       dtype=np.int32)
+            if sp is not None:
+                sp.attrs["n_miss"] = n_miss
+        with maybe_span(tele, "fill_miss", rows=n_miss):
+            staging = self._staging.acquire(m_pad, self._staging_width())
+            D = self.g.feat_dim
+            if n_miss:
+                miss_ids = ids[~hit]
+                staging[:n_miss, :D] = (
+                    self.store.gather(miss_ids, step=step, dev=self.dev)
+                    if self.store is not None
+                    else self.g.get_features(miss_ids))
+            staging[n_miss:, :D] = 0.0
         spec.ids = ids_p
         spec.cache_pos = pos_p
         spec.hit = hit_p
@@ -440,6 +468,7 @@ class DeviceBatchBuilder(BatchBuilder):
         spec.miss_inv = miss_inv
         spec.n_miss = n_miss
         spec.cache_epoch = self.cache.epoch
+        spec.step = step
         return spec
 
     def release_spec(self, spec):
@@ -465,7 +494,8 @@ class DeviceBatchBuilder(BatchBuilder):
         table = self._table(spec.cache_epoch)
         # jnp.array copies, but the copy is DISPATCHED, not done: the
         # transfer must complete before the staging buffer goes back to
-        # the pool, or the next fill overwrites it mid-read
+        # the pool, or the next fill overwrites it mid-read.  The span
+        # takes its step from the enclosing finalize span.
         with maybe_span(self.telemetry, "h2d_staging", dev=self.dev,
                         rows=spec.n_miss):
             miss = jnp.array(spec.miss_feats)
@@ -480,7 +510,8 @@ class DeviceBatchBuilder(BatchBuilder):
     def finalize(self, spec):
         if not self.fused:
             return self._finalize_unfused(spec)
-        with maybe_span(self.telemetry, "finalize", dev=self.dev):
+        with maybe_span(self.telemetry, "finalize", step=spec.step,
+                        dev=self.dev):
             return _get_fused_finalize()(*self.finalize_args(spec),
                                          impl=self.gather, D=self.g.feat_dim)
 

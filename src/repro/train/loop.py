@@ -564,7 +564,7 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
                           builder=builder, jr=jr):
                     seeds = tablet[rng.integers(0, len(tablet),
                                                 size=per_dev)]
-                    spec = builder.build_spec(seeds, rng)
+                    spec = builder.build_spec(seeds, rng, step=step)
                     if jr is not None:
                         jr.record(step + 1, rng)
                     return spec

@@ -103,9 +103,9 @@ class Prefetcher:
 
         ``telemetry`` (a repro.obs.Telemetry) instruments the pipeline:
         spans around each step's build/pack and the refresh hook (on the
-        prefetch thread) and around every ``get()`` (consumer thread),
-        plus build-time and queue-dry histograms in the registry.  With
-        the default ``None`` not one telemetry instruction runs.
+        prefetch thread) and around every ``get()`` (consumer thread,
+        carrying the step of the batch it returned).  With the default
+        ``None`` not one telemetry instruction runs.
 
         ``start_step`` is the first step the worker builds (a resumed run
         passes its checkpoint boundary so the batch sequence — and every
@@ -159,9 +159,6 @@ class Prefetcher:
         self._pack_fn = pack_fn
         self._extra_summary = extra_summary
         self._tele = telemetry
-        if telemetry is not None:
-            self._h_build = telemetry.registry.histogram("prefetch.build_s")
-            self._h_dry = telemetry.registry.histogram("prefetch.dry_s")
         self._build_s = 0.0
         self._pack_s = 0.0
         self._built = 0
@@ -234,7 +231,6 @@ class Prefetcher:
             if tele is not None:
                 with tele.span("prefetch_build", step=self._step):
                     batch = self._build(self._step)
-                self._h_build.observe(time.perf_counter() - t0)
             else:
                 batch = self._build(self._step)
             self._build_s += time.perf_counter() - t0
@@ -246,11 +242,12 @@ class Prefetcher:
                 else:
                     batch = self._pack_fn(batch)
                 self._pack_s += time.perf_counter() - t0
+            item = (self._step, batch)
             self._built += 1
             self._step += 1
             while not self._stop.is_set():
                 try:
-                    self._q.put(batch, timeout=0.1)
+                    self._q.put(item, timeout=0.1)
                     break
                 except queue.Full:
                     continue
@@ -261,17 +258,17 @@ class Prefetcher:
         empty queue (a dead worker used to mean a bare ``queue.Empty``
         after the full timeout).  Wall time spent in here is accumulated as
         queue-dry (device-stall) time for ``summary()`` (and, with
-        telemetry, a consumer-thread span + the queue-dry histogram)."""
+        telemetry, a consumer-thread ``prefetch_get`` span that carries
+        the step of the batch it returned)."""
         if self._tele is None:
-            return self._get(timeout)
-        t0 = time.perf_counter()
-        with self._tele.span("prefetch_get"):
-            try:
-                return self._get(timeout)
-            finally:
-                self._h_dry.observe(time.perf_counter() - t0)
+            return self._get(timeout)[1]
+        with self._tele.span("prefetch_get") as sp:
+            step, item = self._get(timeout)
+            sp.step = step
+            return item
 
-    def _get(self, timeout: float) -> dict:
+    def _get(self, timeout: float) -> tuple:
+        """(step, batch) of the next prefetched batch."""
         t0 = time.perf_counter()
         deadline = t0 + timeout
         try:

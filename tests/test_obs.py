@@ -388,3 +388,184 @@ def test_reporter_prints_histogram_quantiles(run, capsys):
     out = capsys.readouterr().out
     assert "histograms (interpolated quantiles)" in out
     assert "step.time_s" in out
+
+
+# ---------------- build-stage spans and step inheritance ----------------
+
+class _Recording(Telemetry):
+    """Keeps every completed span in memory, as (name, t0, dur, thread,
+    step, attrs)."""
+
+    def __init__(self):
+        super().__init__(TelemetryConfig(jax_annotations=False))
+        self.recs = []
+
+    def _record_span(self, name, t0_ns, dur_ns, tid, thread, step, attrs):
+        super()._record_span(name, t0_ns, dur_ns, tid, thread, step, attrs)
+        self.recs.append((name, t0_ns, dur_ns, thread, step, dict(attrs)))
+
+
+LEAVES = ("sample_dispatch", "sample_sync", "sample_repair",
+          "sample_account", "spec_dedup", "fill_split", "fill_miss")
+
+
+def _half_cached():
+    """A graph and a cache over half its vertices (by degree), so the
+    device sampler's resolve repairs rows from the mirror and from the
+    host CSR, and the fill has misses."""
+    from repro.core.unified_cache import CliqueCache
+
+    g = powerlaw_graph(3000, 8, seed=9, feat_dim=16)
+    order = np.argsort(-(g.indptr[1:] - g.indptr[:-1]), kind="stable")
+    ids = np.sort(order[: g.n // 2]).astype(np.int64)
+    parts = np.array_split(ids, 4)
+    return g, CliqueCache(g, list(range(4)), [p[:8] for p in parts], parts,
+                          topology_mode="sharded")
+
+
+def test_span_step_inherits_from_innermost_open_span():
+    tele = _Recording()
+    with tele.span("outer", step=4):
+        with tele.span("mid"):
+            with tele.span("leaf"):
+                pass
+        with tele.span("own", step=9):
+            with tele.span("under_own"):
+                pass
+    with tele.span("root"):
+        pass
+    steps = {r[0]: r[4] for r in tele.recs}
+    assert steps == {"outer": 4, "mid": 4, "leaf": 4, "own": 9,
+                     "under_own": 9, "root": None}
+    assert tele.open_spans == 0
+    tele.close()
+
+
+def test_device_build_emits_the_seven_leaf_spans_in_order():
+    from repro.train.batch import DeviceBatchBuilder
+
+    g, cache = _half_cached()
+    tele = _Recording()
+    builder = DeviceBatchBuilder(g, cache, (5, 3), dev=0, gather="xla")
+    builder.telemetry = tele
+    rng = np.random.default_rng(0)
+    for step in (3, 4):
+        with tele.span("spec_build", step=step):
+            spec = builder.build_spec(rng.integers(0, g.n, 64), rng,
+                                      step=step)
+        assert spec.step == step
+    builds = [r for r in tele.recs if r[0] == "spec_build"]
+    assert len(builds) == 2
+    for b in builds:
+        inside = sorted((r for r in tele.recs if r[0] != "spec_build"
+                         and b[1] <= r[1] and r[1] + r[2] <= b[1] + b[2]),
+                        key=lambda r: r[1])
+        assert tuple(r[0] for r in inside) == LEAVES
+        assert {r[4] for r in inside} == {b[4]}  # the parent's step
+        assert {r[3] for r in inside} == {b[3]}  # the parent's thread
+        attrs = {r[0]: r[5] for r in inside}
+        assert attrs["sample_dispatch"]["draws"] == 64 * 5 + 64 * 5 * 3
+        assert attrs["sample_sync"]["rows"] == 64 + 64 * 5
+        assert attrs["fill_miss"]["rows"] == attrs["fill_split"]["n_miss"]
+        assert attrs["spec_dedup"]["n_ids"] >= attrs["fill_split"]["n_miss"]
+    assert tele.open_spans == 0
+    tele.close()
+
+
+def test_sample_repair_counts_the_rows_it_repaired(monkeypatch):
+    from repro.graph import sampling
+    from repro.train.batch import DeviceBatchBuilder
+
+    g, cache = _half_cached()
+    seen = {"mirror": 0, "host": 0}
+    real_mirror, real_host = (sampling._mirror_sample_level,
+                              sampling.host_sample_level)
+
+    def mirror(cache_, seeds, fanout, rand):
+        seen["mirror"] += len(seeds)
+        return real_mirror(cache_, seeds, fanout, rand)
+
+    def host(g_, seeds, fanout, rng, rand=None):
+        seen["host"] += len(seeds)
+        return real_host(g_, seeds, fanout, rng, rand=rand)
+
+    monkeypatch.setattr(sampling, "_mirror_sample_level", mirror)
+    monkeypatch.setattr(sampling, "host_sample_level", host)
+    tele = _Recording()
+    builder = DeviceBatchBuilder(g, cache, (5, 3), dev=0, gather="xla")
+    builder.telemetry = tele
+    rng = np.random.default_rng(1)
+    for _ in range(3):
+        builder.build_spec(rng.integers(0, g.n, 64), rng)
+    reps = [r[5] for r in tele.recs if r[0] == "sample_repair"]
+    assert len(reps) == 3
+    assert sum(a["mirror_rows"] for a in reps) == seen["mirror"] > 0
+    assert sum(a["host_rows"] for a in reps) == seen["host"] > 0
+    tele.close()
+
+
+def test_consumer_spans_carry_their_batch_step(tiny):
+    g, plan = tiny
+    cfg = GNNConfig(feat_dim=16, hidden=8, batch_size=64, fanouts=(4, 2))
+    tele = _Recording()
+    train_gnn(g, plan, cfg, steps=5, seed=0, backend="device", gather="xla",
+              telemetry=tele)
+    by = {}
+    for r in tele.recs:
+        by.setdefault(r[0], []).append(r)
+    # one get per step; one build, finalize and staging copy per device
+    n_dev = len(plan.partition.tablets)
+    gets = sorted(by["prefetch_get"], key=lambda r: r[1])
+    assert [r[4] for r in gets] == list(range(5))
+    for name in ("finalize", "h2d_staging", "spec_build"):
+        assert sorted(r[4] for r in by[name]) \
+            == sorted(list(range(5)) * n_dev), name
+    # each batch's finalizes follow the get that returned it
+    for get in gets:
+        fins = [r for r in by["finalize"] if r[4] == get[4]]
+        assert len(fins) == n_dev
+        assert all(get[1] + get[2] <= r[1] for r in fins)
+    # every build leaf carries the step of the spec_build around it
+    for b in by["spec_build"]:
+        inside = [r for r in tele.recs if r[0] in LEAVES
+                  and r[3] == b[3]
+                  and b[1] <= r[1] and r[1] + r[2] <= b[1] + b[2]]
+        assert sorted(r[0] for r in inside) == sorted(LEAVES)
+        assert {r[4] for r in inside} == {b[4]}
+
+
+def test_disabled_device_backend_runs_no_telemetry_code(tiny):
+    g, plan = tiny
+    cfg = GNNConfig(feat_dim=16, hidden=8, batch_size=64, fanouts=(4, 2))
+    before = activity_count()
+    res = train_gnn(g, plan, cfg, steps=3, seed=0, backend="device",
+                    gather="xla")
+    assert res.telemetry == {}
+    assert activity_count() == before
+
+
+def test_digest_self_time_subtracts_same_thread_children(tmp_path):
+    """Self time is a span's duration less its direct children on the same
+    thread: a grandchild counts against its parent only, and a span on
+    another thread that overlaps in time counts against nothing."""
+    path = tmp_path / "nested.jsonl"
+    lines = [{"v": SCHEMA_VERSION, "kind": "meta", "run": "x", "window": 1,
+              "t0_unix_s": 0.0, "pid": 1}]
+
+    def span(name, ts, dur, tid):
+        lines.append({"v": SCHEMA_VERSION, "kind": "span", "name": name,
+                      "ts_us": float(ts), "dur_us": float(dur), "tid": tid,
+                      "thread": f"t{tid}"})
+
+    span("finalize", 0, 100, 1)
+    span("h2d_staging", 10, 30, 1)
+    span("leaf", 15, 5, 1)
+    span("finalize", 200, 50, 1)
+    span("other", 20, 60, 2)
+    path.write_text("".join(json.dumps(ln) + "\n" for ln in lines))
+    d = digest(load_stream(str(path)))
+    assert d["spans"]["finalize"]["self_s"] == pytest.approx(120e-6)
+    assert d["spans"]["h2d_staging"]["self_s"] == pytest.approx(25e-6)
+    assert d["spans"]["leaf"]["self_s"] == pytest.approx(5e-6)
+    assert d["spans"]["other"]["self_s"] == pytest.approx(60e-6)
+    assert report_main([str(path)]) == 0
