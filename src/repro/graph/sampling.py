@@ -281,3 +281,30 @@ def unique_vertices(levels: List[np.ndarray]) -> np.ndarray:
     flat = np.concatenate([l.reshape(-1) for l in levels])
     flat = flat[flat >= 0]
     return np.unique(flat)
+
+
+def dedup_levels(levels: List[np.ndarray],
+                 slot: np.ndarray) -> Tuple[np.ndarray, List[np.ndarray]]:
+    """``unique_vertices(levels)`` and each level's positions into it.
+
+    ``slot`` is a caller-owned int32 scratch map with one entry per vertex
+    of the graph.  It needs no initialization and may hold any values from
+    earlier calls: every entry read here is written earlier in the same
+    call.  Returns ``(ids, level_pos)`` with ``ids``
+    sorted int64 (equal to ``unique_vertices``) and ``level_pos[l]`` the
+    int64 index of each entry of ``levels[l]`` in ``ids`` (0 at padding
+    entries).  Cost scales with the sampled count, not with ``len(slot)``:
+    one scatter and two gathers over the sampled ids, and a sort of the
+    distinct ones only."""
+    flat = np.concatenate([l.reshape(-1) for l in levels])
+    v = flat[flat >= 0]
+    order = np.arange(len(v), dtype=np.int32)
+    slot[v] = order
+    # the last writer wins, so each distinct vertex keeps one occurrence
+    ids = v[slot[v] == order].astype(np.int64, copy=False)
+    ids.sort()
+    slot[ids] = np.arange(len(ids), dtype=np.int32)
+    # mode="clip" reads padding (-1) at slot[0]; the where masks it
+    level_pos = [np.where(lvl >= 0, slot.take(lvl, mode="clip"), np.int64(0))
+                 for lvl in levels]
+    return ids, level_pos

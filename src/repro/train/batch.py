@@ -67,7 +67,7 @@ import numpy as np
 from repro.core.unified_cache import CliqueCache, TrafficCounter
 from repro.graph.csr import CSRGraph
 from repro.graph.sampling import (cache_sample_batch, cache_sample_dispatch,
-                                  host_sample_batch, unique_vertices)
+                                  dedup_levels, host_sample_batch)
 from repro.obs import maybe_span
 
 BACKENDS = ("host", "device", "sharded")
@@ -150,14 +150,6 @@ class _StagingPool:
             self._free.setdefault(buf.shape, deque()).append(buf)
 
 
-def _level_positions(ids: np.ndarray, levels: List[np.ndarray]) -> List[np.ndarray]:
-    out = []
-    for lvl in levels:
-        pos = np.searchsorted(ids, np.maximum(lvl, 0))
-        out.append(np.clip(pos, 0, max(len(ids) - 1, 0)))
-    return out
-
-
 _fused_finalize = None  # built on first use (keeps jax import lazy)
 
 
@@ -197,7 +189,13 @@ def _get_fused_finalize():
 
 
 class BatchBuilder:
-    """Samples and extracts one device's mini-batches (see module doc)."""
+    """Samples and extracts one device's mini-batches (see module doc).
+
+    Not thread-safe: a builder owns a vertex-indexed slot map (4 bytes a
+    vertex, allocated on the first build) that every ``sample_spec``
+    overwrites, so one builder runs on one thread at a time.  Each device
+    has its own builder, and the Prefetcher's part pool never runs one
+    device's part on two threads at once."""
 
     backend: str = "?"
 
@@ -224,6 +222,13 @@ class BatchBuilder:
         # host-RAM/SSD tiers instead of a direct g.get_features host read.
         # Rows are bitwise identical either way.
         self.store = None
+        self._slot: Optional[np.ndarray] = None  # dedup_levels' scratch map
+
+    def _dedup(self, levels: List[np.ndarray]):
+        """(sorted unique ids, per-level positions into them)."""
+        if self._slot is None:
+            self._slot = np.empty(self.g.n, dtype=np.int32)
+        return dedup_levels(levels, self._slot)
 
     # -- phase 1: host thread --------------------------------------------
     # Split into two sub-phases so the pipeline can sample *ahead* of the
@@ -303,10 +308,9 @@ class HostBatchBuilder(BatchBuilder):
             with self.counter.lock:
                 self.counter.host_sample_syncs += 1
         self._account_sampling(levels)
-        ids = unique_vertices(levels)
+        ids, level_pos = self._dedup(levels)
         return BatchSpec(labels=self.g.get_labels(seeds), levels=levels,
-                         ids=ids, level_pos=_level_positions(ids, levels),
-                         n_ids=len(ids))
+                         ids=ids, level_pos=level_pos, n_ids=len(ids))
 
     def fill_spec(self, spec, step=None):
         ids = spec.ids
@@ -413,10 +417,11 @@ class DeviceBatchBuilder(BatchBuilder):
         with maybe_span(tele, "sample_account"):
             self._account_sampling(levels)
         with maybe_span(tele, "spec_dedup") as sp:
-            ids = unique_vertices(levels)
-            level_pos = _level_positions(ids, levels)
+            ids, level_pos = self._dedup(levels)
             if sp is not None:
                 sp.attrs["n_ids"] = len(ids)
+                sp.attrs["n_sampled"] = int(sum(
+                    np.count_nonzero(lvl >= 0) for lvl in levels))
         return BatchSpec(labels=labels, levels=levels, ids=ids,
                          level_pos=level_pos, n_ids=len(ids))
 

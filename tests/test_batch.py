@@ -1,6 +1,8 @@
 """Host/device batch-pipeline parity: the two backends must be
 interchangeable — identical subgraph shapes, identical hit/miss accounting,
 identical batches, matching loss trajectories."""
+import threading
+
 import numpy as np
 import pytest
 
@@ -8,7 +10,8 @@ from repro.core.cliques import topology_matrix
 from repro.core.planner import build_plan
 from repro.core.unified_cache import TrafficCounter
 from repro.graph.csr import powerlaw_graph
-from repro.graph.sampling import cache_sample_batch, host_sample_batch
+from repro.graph.sampling import (cache_sample_batch, host_sample_batch,
+                                  unique_vertices)
 from repro.models.gnn import GNNConfig
 from repro.train.batch import (DeviceBatchBuilder, HostBatchBuilder,
                                make_batch_builder)
@@ -212,3 +215,44 @@ def test_make_batch_builder_validation(setup):
     b = make_batch_builder("host", g, None, FANOUTS)
     batch = b.build(np.arange(32), np.random.default_rng(0))
     assert batch["feats_0"].shape == (32, g.feat_dim)
+
+
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_builders_on_two_threads_match_serial_builds(setup, backend):
+    """Each builder owns its dedup slot map: two devices' builders sampling
+    concurrently on two threads give the specs a serial build gives.
+    (With one map shared between them, the host case mismatches.)"""
+    g, plan = setup
+    steps, batch = 30, 4096
+
+    def specs(dev, out, barrier=None):
+        bh, bd, _, _ = _builders(g, plan, dev=dev)
+        builder = bh if backend == "host" else bd
+        rng = np.random.default_rng(100 + dev)
+        tablet = plan.partition.tablets[dev]
+        if barrier is not None:
+            barrier.wait()
+        for _ in range(steps):
+            seeds = tablet[rng.integers(0, len(tablet), size=batch)]
+            out.append(builder.sample_spec(seeds, rng))
+
+    serial = {d: [] for d in (0, 1)}
+    for d in (0, 1):
+        specs(d, serial[d])
+    threaded = {d: [] for d in (0, 1)}
+    barrier = threading.Barrier(2)
+    workers = [threading.Thread(target=specs, args=(d, threaded[d], barrier))
+               for d in (0, 1)]
+    for t in workers:
+        t.start()
+    for t in workers:
+        t.join(timeout=120)
+        assert not t.is_alive()
+    for d in (0, 1):
+        assert len(threaded[d]) == steps
+        for a, b in zip(serial[d], threaded[d]):
+            np.testing.assert_array_equal(a.ids, unique_vertices(a.levels))
+            np.testing.assert_array_equal(a.ids, b.ids)
+            for pa, pb in zip(a.level_pos, b.level_pos):
+                assert pa.dtype == pb.dtype
+                np.testing.assert_array_equal(pa, pb)

@@ -472,6 +472,23 @@ def test_device_build_emits_the_seven_leaf_spans_in_order():
     tele.close()
 
 
+def test_spec_dedup_counts_sampled_and_unique_ids():
+    from repro.train.batch import DeviceBatchBuilder
+
+    g, cache = _half_cached()
+    tele = _Recording()
+    builder = DeviceBatchBuilder(g, cache, (5, 3), dev=0, gather="xla")
+    builder.telemetry = tele
+    rng = np.random.default_rng(1)
+    spec = builder.sample_spec(rng.integers(0, g.n, 64), rng)
+    (attrs,) = [r[5] for r in tele.recs if r[0] == "spec_dedup"]
+    assert attrs["n_sampled"] == sum(int((l >= 0).sum())
+                                     for l in spec.levels)
+    assert attrs["n_ids"] == spec.n_ids == len(spec.ids)
+    assert attrs["n_ids"] <= attrs["n_sampled"]
+    tele.close()
+
+
 def test_sample_repair_counts_the_rows_it_repaired(monkeypatch):
     from repro.graph import sampling
     from repro.train.batch import DeviceBatchBuilder
