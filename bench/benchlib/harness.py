@@ -169,13 +169,16 @@ def plan_for(g, tv, cfg: dict):
 
 
 def gnn_config(cfg: dict):
+    """The program's model configuration; the configuration's optional
+    ``model_args`` pass as keywords, so one it does not know fails."""
     from repro.models.gnn import GNNConfig
 
     return GNNConfig(name=cfg["name"], model=cfg["model"],
                      feat_dim=cfg["feat_dim"], hidden=cfg["hidden"],
                      n_classes=cfg["n_classes"],
                      fanouts=tuple(cfg["fanouts"]),
-                     batch_size=cfg["batch_size"], lr=cfg["optimizer"]["lr"])
+                     batch_size=cfg["batch_size"], lr=cfg["optimizer"]["lr"],
+                     **cfg.get("model_args", {}))
 
 
 def census(sampler: refgnn.Sampler, cache, skip: int, steps: int,
@@ -282,21 +285,27 @@ def compare(prog: dict, ref: dict, opt: dict) -> Dict[str, float]:
             "_left_out": left_out}
 
 
+def ref_shapes(cfg: dict, model) -> dict:
+    """The reference's parameter leaves for this configuration."""
+    return refgnn.param_shapes(model, cfg["feat_dim"], cfg["hidden"],
+                               cfg["n_classes"], len(cfg["fanouts"]),
+                               cfg.get("model_args"))
+
+
 def reference_steps(cfg: dict, g, tv, seed: int, steps: int,
                     dtype: str = "float32", batches=None,
                     keep: bool = False) -> dict:
     """The reference's first ``steps`` steps from ``seed``, one batch at a
     time, at the configuration's matmul precision."""
     model = refgnn.load_model(cfg["model"])
-    shapes = refgnn.param_shapes(model, cfg["feat_dim"], cfg["hidden"],
-                                 cfg["n_classes"], len(cfg["fanouts"]))
+    shapes = ref_shapes(cfg, model)
     sampler = refgnn.Sampler(g.indptr, g.indices,
                              refgnn.tablet(tv, cfg["plan_seed"]),
                              cfg["batch_size"], cfg["fanouts"], seed)
     return refgnn.run(model, cfg["optimizer"], shapes, g.features, sampler,
                       cfg["graph_seed"], cfg["n_classes"], seed, steps,
                       cfg["matmul_precision"], dtype=dtype, batches=batches,
-                      keep=keep)
+                      keep=keep, model_args=cfg.get("model_args"))
 
 
 def train_kwargs(cfg: dict, traffic: dict, seed: int, gather: str,
@@ -336,9 +345,8 @@ def first_steps(g, plan, cfg: dict, traffic: dict, seed: int, gather: str,
         losses += list(res.losses)
         feature_requests += res.counter.feature_requests
         topo_requests += res.counter.topo_requests
-    paths = refgnn.leaf_paths(refgnn.param_shapes(
-        refgnn.load_model(cfg["model"]), cfg["feat_dim"], cfg["hidden"],
-        cfg["n_classes"], len(cfg["fanouts"])))
+    paths = refgnn.leaf_paths(ref_shapes(cfg,
+                                         refgnn.load_model(cfg["model"])))
     ck1 = read_checkpoint(os.path.join(ckpt_dir, f"ckpt_{1:08d}.npz"), paths)
     ckw = read_checkpoint(os.path.join(ckpt_dir, f"ckpt_{W:08d}.npz"), paths)
     return ({"losses": losses, "m1": ck1["m"], "p": ckw["p"],

@@ -12,11 +12,33 @@ reproduce from the same seed:
   neighbour ``indices[indptr[v] + r % deg(v)]``, -1 where ``v < 0`` or
   ``deg(v) == 0``;
 * labels: the splitmix hash of the vertex id modulo the class count;
-* layers: the equations of ``bench/reference/<model>.py``; masked mean
-  aggregation; the parameters initialised leaf by leaf from
-  ``split(PRNGKey(seed), n_leaves)`` in sorted-key order, normal with
-  standard deviation ``1/sqrt(fan_in)``, biases zero;
+* layers: the equations of ``bench/reference/<model>.py``; the parameters
+  initialised leaf by leaf from ``split(PRNGKey(seed), n_leaves)`` in
+  sorted-key order, normal with standard deviation ``1/sqrt(fan_in)``,
+  biases zero;
 * optimizer: AdamW with global-norm gradient clipping.
+
+A layer module ``bench/reference/<model>.py`` owns everything that differs
+between models.  It provides four functions; each takes the layer's index
+``li`` and the layer count ``n_layers`` first, and the configuration's
+optional ``"model_args"`` object as keywords last:
+
+* ``layer_params(li, n_layers, d_in, hidden, n_classes, **model_args)``
+  -> ``(leaves, d_out)``: the layer's parameter leaves, name -> (shape,
+  init), with the shape's first axis its fan-in and init ``"normal"`` or
+  ``"zeros"``, and the width of the rows the layer outputs;
+* ``has_head(**model_args)`` -> whether a linear ``head`` of shape
+  ``(d_out of the last layer, n_classes)`` follows the last layer;
+* ``layer(li, n_layers, p, h_self, h_neigh, mask, precision,
+  **model_args)``: one level's new rows from its own rows ``h_self``
+  ``(..., d_in)``, the rows of the level below ``h_neigh`` ``(..., f,
+  d_in)`` and their validity ``mask`` ``(..., f)``; the aggregation is the
+  module's (``masked_mean`` serves the mean aggregators);
+* ``layer_flops(li, n_layers, rows, rows_below, d_in, d_out, grad_in,
+  **model_args)`` -> ``(forward, backward)`` FLOPs of the layer on one
+  level of ``rows`` rows over ``rows_below`` neighbour rows;
+  ``grad_in`` says whether the layer's input carries a gradient
+  (``benchlib.counts.step_flops`` sums these).
 
 Precision is the configuration's: float32 arrays with its stated matmul
 precision (``"default"`` on a TPU is one bfloat16 pass with float32
@@ -29,7 +51,7 @@ from __future__ import annotations
 import importlib.util
 import math
 import os
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -120,14 +142,30 @@ def topo_requests(levels: Sequence[np.ndarray]) -> int:
 
 # ---- model ----------------------------------------------------------------
 
+def masked_mean(x, mask):
+    """Mean of ``x`` (..., f, D) over its valid slots ``mask`` (..., f):
+    the mean aggregators' aggregation, 0 where no slot is valid."""
+    import jax.numpy as jnp
+
+    m = mask.astype(x.dtype)[..., None]
+    return (x * m).sum(axis=-2) / jnp.maximum(m.sum(axis=-2), 1.0)
+
+
+def masked_mean_flops(rows_below: int, d: int) -> int:
+    """``masked_mean``'s FLOPs over ``rows_below`` rows of width ``d``: one
+    multiply and one add per element (its backward counts the same)."""
+    return 2 * rows_below * d
+
+
 def param_shapes(model, feat_dim: int, hidden: int, n_classes: int,
-                 n_layers: int) -> Dict:
+                 n_layers: int, model_args: Optional[Dict] = None) -> Dict:
+    args = model_args or {}
     out, d_in = {}, feat_dim
     for li in range(n_layers):
-        out[f"layer{li}"] = {k: (shape(d_in, hidden), init)
-                             for k, (shape, init) in model.LAYER_PARAMS.items()}
-        d_in = hidden
-    out["head"] = ((d_in, n_classes), "normal")
+        out[f"layer{li}"], d_in = model.layer_params(
+            li, n_layers, d_in, hidden, n_classes, **args)
+    if model.has_head(**args):
+        out["head"] = ((d_in, n_classes), "normal")
     return out
 
 
@@ -165,37 +203,39 @@ def init_params(shapes: Dict, seed: int) -> Dict:
     return out
 
 
-def forward(model, params, feats, masks, precision):
+def forward(model, params, feats, masks, precision,
+            model_args: Optional[Dict] = None):
     """feats[l]: (B, f1..fl, D); masks[l] (l >= 1): (B, f1..fl) -> logits."""
     import jax.numpy as jnp
 
+    args = model_args or {}
     n_layers = len(feats) - 1
     h = list(feats)
     for li in range(n_layers):
         p = params[f"layer{li}"]
-        new = []
-        for lev in range(n_layers - li):
-            m = masks[lev + 1].astype(h[lev + 1].dtype)[..., None]
-            agg = ((h[lev + 1] * m).sum(axis=-2)
-                   / jnp.maximum(m.sum(axis=-2), 1.0))
-            new.append(model.layer(p, h[lev], agg, precision))
-        h = new
+        h = [model.layer(li, n_layers, p, h[lev], h[lev + 1], masks[lev + 1],
+                         precision, **args)
+             for lev in range(n_layers - li)]
+    if "head" not in params:
+        return h[0]
     return jnp.matmul(h[0], params["head"].astype(h[0].dtype),
                       precision=precision)
 
 
-def loss(model, params, feats, masks, y, precision):
+def loss(model, params, feats, masks, y, precision,
+         model_args: Optional[Dict] = None):
     import jax
     import jax.numpy as jnp
 
-    logits = forward(model, params, feats, masks, precision).astype(
-        jnp.float32)
+    logits = forward(model, params, feats, masks, precision,
+                     model_args).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     ll = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
     return (lse - ll).mean()
 
 
-def make_step(model, opt: Dict, dtype: str, precision: str):
+def make_step(model, opt: Dict, dtype: str, precision: str,
+              model_args: Optional[Dict] = None):
     """One jitted training step of the reference:
     (params, m, v, count, feats, masks, y) -> (params, m, v, count, loss,
     clipped grads)."""
@@ -212,7 +252,7 @@ def make_step(model, opt: Dict, dtype: str, precision: str):
 
         def f(p):
             pc = jax.tree.map(lambda x: x.astype(cdt), p)
-            return loss(model, pc, feats, masks, y, precision)
+            return loss(model, pc, feats, masks, y, precision, model_args)
 
         val, g = jax.value_and_grad(f)(params)
         g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
@@ -248,13 +288,13 @@ def batch_arrays(X: np.ndarray, levels: Sequence[np.ndarray], graph_seed: int,
 def run(model, opt: Dict, shapes: Dict, X: np.ndarray, sampler: Sampler,
         graph_seed: int, n_classes: int, seed: int, steps: int,
         precision: str, dtype: str = "float32", batches=None,
-        keep: bool = False) -> Dict:
+        keep: bool = False, model_args: Optional[Dict] = None) -> Dict:
     """``steps`` reference steps from ``seed``.  Returns the per-step
     losses, the first step's clipped gradient, the parameters before and
     after (each a dict of leaf path -> numpy array), and the sampled
     batches' unique-id and adjacency-read counts.  ``batches`` (host batch
     arrays per step, as ``keep=True`` returns them) skips sampling and
-    gathering again."""
+    gathering again; ``model_args`` go to the layer module."""
     import jax
     import jax.numpy as jnp
 
@@ -262,7 +302,7 @@ def run(model, opt: Dict, shapes: Dict, X: np.ndarray, sampler: Sampler,
     p0 = params
     zeros = jax.tree.map(jnp.zeros_like, params)
     m, v, count = zeros, zeros, jnp.zeros((), jnp.int32)
-    step = make_step(model, opt, dtype, precision)
+    step = make_step(model, opt, dtype, precision, model_args)
     losses, grad0, kept = [], None, []
     n_unique = n_topo = 0
     for i in range(steps):

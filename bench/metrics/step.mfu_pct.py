@@ -8,7 +8,8 @@ def read(run):
     cfg = run.config
     flops = counts.step_flops(refgnn.load_model(cfg["model"]),
                               cfg["batch_size"], cfg["fanouts"],
-                              cfg["feat_dim"], cfg["hidden"], cfg["n_classes"])
+                              cfg["feat_dim"], cfg["hidden"], cfg["n_classes"],
+                              cfg.get("model_args"))
     t0, t1 = run.window_ns
     steps_per_s = len(run.window_steps) / ((t1 - t0) / 1e9)
     return 100.0 * flops * steps_per_s / peaks.peak(run.device_kind)["bf16_flops"]
