@@ -395,25 +395,49 @@ class DeviceBatchBuilder(BatchBuilder):
         # spans (with telemetry): sample_dispatch, sample_sync and
         # sample_repair (inside resolve), sample_account, spec_dedup; the
         # step they carry is the enclosing spec_build's
-        tele = self.telemetry
         if self.sampler == "chain":
-            # dispatch the whole device chain, then fetch labels while it
-            # is in flight; resolve() pays the single sync and repairs
-            # stale-parent / host-miss rows (see cache_sample_dispatch)
-            with maybe_span(tele, "sample_dispatch") as sp:
-                resolve = cache_sample_dispatch(self.g, self.cache, seeds,
-                                                self.fanouts, rng,
-                                                telemetry=tele)
-                labels = self.g.get_labels(seeds)
-                if sp is not None:
-                    sp.attrs["draws"] = int(
-                        len(seeds) * np.cumprod(self.fanouts).sum())
-            levels, _topo_hits = resolve(counter=self.counter)
-        else:
-            levels, _topo_hits = cache_sample_batch(
-                self.g, self.cache, seeds, self.fanouts, rng, chain=False,
-                counter=self.counter)
+            return self.resolve_spec(self.sample_dispatch(seeds, rng))
+        levels, _topo_hits = cache_sample_batch(
+            self.g, self.cache, seeds, self.fanouts, rng, chain=False,
+            counter=self.counter)
+        return self._spec(levels, self.g.get_labels(seeds))
+
+    def sample_dispatch(self, seeds, rng, for_step: Optional[int] = None):
+        """Chain sampler, first half: draw every hop's randomness, enqueue
+        the whole device chain, then fetch labels while it is in flight.
+        Returns the dispatched chain, ``(labels, resolve)``, for
+        ``resolve_spec``.  All of the batch's RNG draws happen here.
+        ``for_step`` (the step the batch is drawn for) becomes the
+        ``sample_dispatch`` span's attr of that name: the train loop's
+        lookahead dispatches batch k+1 inside batch k's build."""
+        with maybe_span(self.telemetry, "sample_dispatch") as sp:
+            resolve = cache_sample_dispatch(self.g, self.cache, seeds,
+                                            self.fanouts, rng,
+                                            telemetry=self.telemetry)
             labels = self.g.get_labels(seeds)
+            if sp is not None:
+                sp.attrs["draws"] = int(
+                    len(seeds) * np.cumprod(self.fanouts).sum())
+                if for_step is not None:
+                    sp.attrs["for_step"] = int(for_step)
+        return labels, resolve
+
+    def resolve_spec(self, chain, after_resolve=None) -> BatchSpec:
+        """Chain sampler, second half: ``resolve()`` pays the single sync
+        and repairs stale-parent / host-miss rows (see
+        ``cache_sample_dispatch``), drawing nothing; then accounting and
+        dedup.  ``after_resolve()``, when given, runs between the resolve
+        and the accounting: the train loop dispatches the next batch's
+        chain there, so it queues on the device ahead of this batch's
+        finalize."""
+        labels, resolve = chain
+        levels, _topo_hits = resolve(counter=self.counter)
+        if after_resolve is not None:
+            after_resolve()
+        return self._spec(levels, labels)
+
+    def _spec(self, levels, labels) -> BatchSpec:
+        tele = self.telemetry
         with maybe_span(tele, "sample_account"):
             self._account_sampling(levels)
         with maybe_span(tele, "spec_dedup") as sp:

@@ -41,8 +41,8 @@ from repro.models.gnn import (GNNConfig, defs as gnn_defs,
                               forward as gnn_forward, loss_fn as gnn_loss)
 from repro.models.params import init_from_defs
 from repro.obs import maybe_span
-from repro.train.batch import (HostBatchBuilder, make_batch_builder,
-                               pack_sharded_specs)
+from repro.train.batch import (DeviceBatchBuilder, HostBatchBuilder,
+                               make_batch_builder, pack_sharded_specs)
 from repro.train.checkpoint import (AsyncCheckpointer,
                                     latest_resumable_checkpoint,
                                     restore_checkpoint)
@@ -457,6 +457,10 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
           "per_dev": max(cfg.batch_size // max(n_dev, 1), 16),
           "prefetcher": None, "finalize": None, "sharded_step": None}
     pipeline_base: dict = {}
+    # one tally per lookahead part closure: builds whose chain the
+    # previous build had dispatched (kept across a remesh, so the total
+    # stays monotonic)
+    sampled_ahead: List[List[int]] = []
     refresh_base: dict = {}
     refresh_events: List[dict] = []
     streams = {}
@@ -559,6 +563,46 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
                                       window=window, limit=steps, dev=d,
                                       start=start_step)
                 build = win.build
+            elif (manager_l is None and isinstance(builder, DeviceBatchBuilder)
+                  and builder.sampler == "chain"):
+                # one-batch sampling lookahead: batch k+1's seeds and hop
+                # draws are drawn, and its device chain enqueued, inside
+                # build k right after k's resolve, so chain(k+1) queues
+                # ahead of finalize(k) and runs while the host dedups and
+                # fills batch k.  The RNG is drawn in the same order as
+                # build_spec's, resolve draws nothing, and accounting stays
+                # at resolve and fill: batches and counts are unchanged,
+                # and a chain never resolved counts nothing.  Off under a
+                # refresh hook, which may swap the cached topology that a
+                # chain dispatched before it would resolve against.
+                pending = {}  # step -> chain dispatched by the last build
+                ahead = [0]
+                sampled_ahead.append(ahead)
+
+                def dispatch(step: int, rng=rng, tablet=tablet,
+                             builder=builder):
+                    seeds = tablet[rng.integers(0, len(tablet),
+                                                size=per_dev)]
+                    return builder.sample_dispatch(seeds, rng, for_step=step)
+
+                def build(step: int, rng=rng, builder=builder, jr=jr,
+                          pending=pending, ahead=ahead):
+                    chain = pending.pop(step, None)
+                    pending.clear()
+                    if chain is None:
+                        chain = dispatch(step)
+                    else:
+                        ahead[0] += 1
+
+                    def dispatch_next():
+                        if jr is not None:
+                            # boundary state: steps <= this one drawn
+                            jr.record(step + 1, rng)
+                        if step + 1 < steps:
+                            pending[step + 1] = dispatch(step + 1)
+
+                    spec = builder.resolve_spec(chain, dispatch_next)
+                    return builder.fill_spec(spec, step=step)
             else:
                 def build(step: int, rng=rng, tablet=tablet,
                           builder=builder, jr=jr):
@@ -688,10 +732,12 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
         # the pipeline cell (add_source replaces by name) with folded
         # base totals, so counters stay monotonic across the swap.
         tele.add_source("traffic", counter.publish_metrics)
-        tele.add_source(
-            "prefetch",
-            lambda reg: st["prefetcher"].publish_metrics(
-                reg, base=pipeline_base))
+
+        def publish_prefetch(reg):
+            st["prefetcher"].publish_metrics(reg, base=pipeline_base)
+            reg.counter("prefetch.sample_ahead").set_total(
+                sum(n for n, in sampled_ahead))
+        tele.add_source("prefetch", publish_prefetch)
         if store is not None:
             tele.add_source("store", store.publish_metrics)
         if st["manager"] is not None:
@@ -813,6 +859,7 @@ def train_gnn(g: CSRGraph, plan: Optional[LegionPlan], cfg: GNNConfig, *,
     for k, v in pipeline_base.items():
         if k in pipe:
             pipe[k] = pipe[k] + v
+    pipe["sample_ahead"] = sum(n for n, in sampled_ahead)
     refresh = {}
     if st["manager"] is not None:
         refresh = st["manager"].summary()

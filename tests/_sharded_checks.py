@@ -98,13 +98,14 @@ def check_routed_neighbor_exchange():
     print("routed neighbor exchange OK")
 
 
-def _train(g, plan, cfg, backend, steps, devices=None):
+def _train(g, plan, cfg, backend, steps, devices=None, sampler="chain"):
     from repro.core.unified_cache import TrafficCounter
     from repro.train.loop import train_gnn
 
     counter = TrafficCounter.for_plan(plan)
     res = train_gnn(g, plan, cfg, steps=steps, seed=0, counter=counter,
-                    backend=backend, gather="xla", devices=devices)
+                    backend=backend, gather="xla", devices=devices,
+                    sampler=sampler)
     return res, counter
 
 
@@ -156,6 +157,21 @@ def check_backend_parity():
     # ...but never across cliques (single clique here: vacuously zero —
     # check_clique_validation covers the 2x2 hierarchy)
     assert c_s.cross_clique_topo_bytes(plan.partition.cliques) == 0
+    # the chain sampler's lookahead runs per device part: every part's
+    # build after its first found its chain dispatched, and the sharded
+    # run equals the stepwise sampler's, which has no lookahead, bit for
+    # bit
+    assert r_d.pipeline["sample_ahead"] == (steps - 1) * N_DEV
+    assert r_s.pipeline["sample_ahead"] == (steps - 1) * N_DEV
+    r_w, c_w = _train(g, plan, cfg, "sharded", steps, sampler="stepwise")
+    assert r_w.pipeline["sample_ahead"] == 0
+    np.testing.assert_array_equal(r_s.losses, r_w.losses)
+    np.testing.assert_array_equal(r_s.accs, r_w.accs)
+    assert (c_s.feature_requests, c_s.feature_hits, c_s.topo_requests,
+            c_s.topo_hits, c_s.pcie_transactions) == \
+           (c_w.feature_requests, c_w.feature_hits, c_w.topo_requests,
+            c_w.topo_hits, c_w.pcie_transactions)
+    np.testing.assert_array_equal(c_s.bytes_matrix, c_w.bytes_matrix)
     print("backend parity OK")
 
 

@@ -542,13 +542,21 @@ def test_consumer_spans_carry_their_batch_step(tiny):
         fins = [r for r in by["finalize"] if r[4] == get[4]]
         assert len(fins) == n_dev
         assert all(get[1] + get[2] <= r[1] for r in fins)
-    # every build leaf carries the step of the spec_build around it
+    # every build leaf carries the step of the spec_build around it.
+    # Each build holds its own batch's leaves once; its sample_dispatch
+    # spans name the steps they draw for: the next step's (the sampling
+    # lookahead), and its own only in the call's first build
     for b in by["spec_build"]:
         inside = [r for r in tele.recs if r[0] in LEAVES
                   and r[3] == b[3]
                   and b[1] <= r[1] and r[1] + r[2] <= b[1] + b[2]]
-        assert sorted(r[0] for r in inside) == sorted(LEAVES)
+        assert sorted(r[0] for r in inside if r[0] != "sample_dispatch") \
+            == sorted(set(LEAVES) - {"sample_dispatch"})
         assert {r[4] for r in inside} == {b[4]}
+        k = b[4]
+        assert sorted(r[5]["for_step"] for r in inside
+                      if r[0] == "sample_dispatch") \
+            == [k] * (k == 0) + [k + 1] * (k + 1 < 5)
 
 
 def test_disabled_device_backend_runs_no_telemetry_code(tiny):
